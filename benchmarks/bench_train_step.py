@@ -47,16 +47,9 @@ checkpoint save call <= max(0.5 x transfer stall, CKPT_FLOOR_US).
 
 from __future__ import annotations
 
-import os
-
-if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
-    # the jit rows need a real DP extent; must be set before importing jax
-    os.environ["XLA_FLAGS"] = (
-        os.environ.get("XLA_FLAGS", "") + " --xla_force_host_platform_device_count=8"
-    ).strip()
-
 import argparse
 import json
+import os
 import tempfile
 import threading
 import time
@@ -64,9 +57,8 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import AxisType, NamedSharding
 
-from repro import compat
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.collectives import buckets, plans
 from repro.configs import registry
@@ -98,8 +90,8 @@ def _jit_step_run(mode: str, dp: int, overlap: bool, steps: int, reps: int):
     """Best-of-``reps`` us/step of the real jitted train step, plus the
     per-step losses (for the bitwise gate)."""
     cfg = registry.get_smoke_config("llama3.2-1b")
-    mesh = compat.make_mesh(
-        (dp,), ("data",), axis_types=compat.default_axis_types(1),
+    mesh = jax.make_mesh(
+        (dp,), ("data",), axis_types=(AxisType.Auto,),
         devices=jax.devices()[:dp],
     )
     tcfg = step_lib.TrainConfig(
@@ -586,7 +578,19 @@ def main(json_path: str = "BENCH_train.json", quick: bool = False, check: bool =
         print("# all checks passed")
 
 
+def use_host_devices(n: int = 8) -> None:
+    """CPU-only entry: split the host into ``n`` XLA devices, since the jit
+    rows need a real DP extent.  Takes effect only before JAX creates its
+    backend, so callers run it before any device query."""
+    if "xla_force_host_platform_device_count" not in os.environ.get("XLA_FLAGS", ""):
+        os.environ["XLA_FLAGS"] = (
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={n}"
+        ).strip()
+
+
 if __name__ == "__main__":
+    use_host_devices()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--json", default="BENCH_train.json", help="output JSON path")
     ap.add_argument("--quick", action="store_true",

@@ -55,6 +55,7 @@ def main(argv=None) -> None:
         bench_train_step,
     )
 
+    bench_train_step.use_host_devices()
     print("name,us_per_call,derived")
     for mod in (bench_mrd, bench_detection, bench_async, bench_compression,
                 bench_train_step):

@@ -38,7 +38,6 @@ from typing import Any, Callable, Dict, Optional
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.collectives import plans
 from repro.core import snapshot
 
@@ -459,7 +458,9 @@ class ConvergenceMonitor:
         }
         if not varying:
             return state
-        return jax.tree.map(lambda x: compat.pvary(x, self._axes()), state)
+        return jax.tree.map(
+            lambda x: jax.lax.pcast(x, self._axes(), to="varying"), state
+        )
 
     def migrate_rows(self, rows, keep):
         """Elastic resize of replicated-then-sharded monitor state.

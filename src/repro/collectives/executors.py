@@ -29,7 +29,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
 
 OPS: dict[str, Callable[[Any, Any], Any]] = {
     "sum": jnp.add,
@@ -78,7 +77,7 @@ class DeviceBackend:
         return jax.lax.axis_index(self.axis)
 
     def size(self) -> int:
-        return compat.axis_size(self.axis)
+        return jax.lax.axis_size(self.axis)
 
     def permute(self, x, pairs):
         if not pairs:

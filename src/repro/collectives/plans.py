@@ -262,9 +262,7 @@ class CollectivePlan:
         """Static axis size; device sizes resolve inside the traced region."""
         if self.p is not None:
             return self.p
-        from repro import compat
-
-        return compat.axis_size(self.axes[axis_index])
+        return jax.lax.axis_size(self.axes[axis_index])
 
     def _sizes(self) -> tuple[int, ...]:
         return tuple(self._size(ph.axis_index) for ph in self._phases())

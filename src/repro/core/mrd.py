@@ -19,7 +19,6 @@ from typing import Callable
 import jax
 import jax.numpy as jnp
 
-from repro import compat
 from repro.collectives import plans
 from repro.collectives.executors import (  # noqa: F401
     OPS,
@@ -49,7 +48,7 @@ def _exec_allreduce_stage(x, st: Stage, be, p: int, op: Callable):
 
 
 def axis_size(axis_name: str) -> int:
-    return compat.axis_size(axis_name)
+    return jax.lax.axis_size(axis_name)
 
 
 def allreduce(tree, axis_name: str, *, op: str | Callable = "sum"):
@@ -211,7 +210,7 @@ def make_allreduce(mesh, axis_name: str, *, op: str = "sum", schedule: str = "mr
                 out = out[: flat.shape[0]].reshape(y.shape)
             return out[None]
 
-        return compat.shard_map(
+        return jax.shard_map(
             local, mesh=mesh, in_specs=spec, out_specs=spec,
             axis_names={axis_name}, check_vma=False,
         )(x)

@@ -68,14 +68,9 @@ class TrainConfig:
 
 
 def manual_rules(rules: shd.ShardingRules) -> shd.ShardingRules:
-    """Rules for a strategy's shard_map body: TP constraints stay live when
-    the runtime supports partial-manual shard_map, otherwise everything is
-    manual and constraints must clear."""
-    from repro import compat
-
-    if compat.partial_manual_shard_map():
-        return rules.manual_region()
-    return rules.full_manual_region()
+    """Rules for a strategy's shard_map body (manual over the DP axes only,
+    so TP constraints on the auto "model" axis stay live)."""
+    return rules.manual_region()
 
 
 def resolve_executor(tcfg: TrainConfig, *, compressed: bool = False) -> str:

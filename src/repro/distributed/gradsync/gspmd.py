@@ -16,7 +16,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.distributed import sharding as shd
 from repro.distributed.gradsync import common, register, register_resize
 from repro.distributed.gradsync.common import TrainConfig
@@ -87,7 +86,7 @@ def make(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
             # replicated — each worker then monitors the same global mean,
             # which stays sound (the staged reduction just becomes uniform).
             pe_spec = P(rules.batch_axes(metrics["per_example"].shape[0]))
-            mon_new, done, val = compat.shard_map(
+            mon_new, done, val = jax.shard_map(
                 mon_fn,
                 mesh=mesh,
                 in_specs=(

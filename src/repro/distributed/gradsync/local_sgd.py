@@ -20,7 +20,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.collectives import plans
 from repro.distributed import sharding as shd
 from repro.distributed.gradsync import common, register, register_resize
@@ -94,7 +93,7 @@ def make(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
         dpP = P(dp_axes)
         dpP_tree = lambda t: jax.tree.map(lambda _: dpP, t)
         bspecs = common.batch_specs(cfg, rules, batch)
-        params_s, opt_s, loss, gnorm = compat.shard_map(
+        params_s, opt_s, loss, gnorm = jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(dpP_tree(state["params"]), dpP_tree(state["opt"]), P(), bspecs),

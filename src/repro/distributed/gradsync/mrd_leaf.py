@@ -24,7 +24,6 @@ import jax.numpy as jnp
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.collectives import buckets, plans
 from repro.distributed import sharding as shd
 from repro.distributed.gradsync import common, register, register_resize
@@ -129,7 +128,7 @@ def make(cfg: ModelConfig, mesh: Mesh, tcfg: TrainConfig):
             mon_state_in = jnp.zeros((dp, 1), jnp.float32)
             mon_spec = dpP
         rep = lambda t: jax.tree.map(lambda _: P(), t)
-        out = compat.shard_map(
+        out = jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(rep(state["params"]), rep(state["opt"]), P(), mon_spec, bspecs),

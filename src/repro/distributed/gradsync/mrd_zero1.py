@@ -28,7 +28,6 @@ import numpy as np
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.collectives import buckets, plans
 from repro.collectives.schedules import pivot
 from repro.distributed import sharding as shd
@@ -250,12 +249,46 @@ def make_zero1(
             layout, overlap_lib.leaf_groups(pshape)
         )
 
+    owner_of_rank = np.asarray(
+        [-1 if o is None else o for o in zero1_owner_segments(mesh, dp_axes)],
+        np.int32,
+    )
+
+    def local_master_row(params):
+        """This rank's ``[1, shard_len]`` slice of
+        :func:`zero1_masters_from_params`, built inside the manual region so
+        no device ever holds the other ranks' rows."""
+        bufs = buckets.pack(
+            jax.tree.map(lambda x: x.astype(jnp.float32), params), layout
+        )
+        if paper_mode:
+            return jnp.concatenate(bufs)[None]
+        rank = jnp.zeros((), jnp.int32)
+        for ax in dp_axes:
+            rank = rank * mesh.shape[ax] + jax.lax.axis_index(ax)
+        seg = jnp.asarray(owner_of_rank)[rank]
+        # 1-D dynamic slices: indexing a [prod_p0, -1] reshape of each bucket
+        # instead takes the TPU compiler minutes at full width
+        row = jnp.concatenate([
+            jax.lax.dynamic_slice_in_dim(
+                b, jnp.maximum(seg, 0) * (b.shape[0] // prod_p0),
+                b.shape[0] // prod_p0,
+            )
+            for b in bufs
+        ])
+        return jnp.where(seg >= 0, row, 0.0)[None]
+
     def init_state(key):
         params = transformer.init_params(cfg, key)
-        masters = zero1_masters_from_params(
-            params, mesh, dp_axes,
-            bucket_bytes=tcfg.bucket_bytes, paper_mode=paper_mode,
-        )
+        # jit: an eager partial-manual shard_map rejects the auto TP axis
+        masters = jax.jit(jax.shard_map(
+            local_master_row,
+            mesh=mesh,
+            in_specs=(jax.tree.map(lambda _: P(), params),),
+            out_specs=P(dp_axes),
+            axis_names=set(dp_axes),
+            check_vma=False,
+        ))(params)
         opt = {
             "master": masters,
             "mu": jnp.zeros((dp, shard_len), jnp.float32),
@@ -396,7 +429,7 @@ def make_zero1(
         else:
             mon_state_in = jnp.zeros((dp, 1), jnp.float32)
             mon_spec = dpP
-        out = compat.shard_map(
+        out = jax.shard_map(
             local_step,
             mesh=mesh,
             in_specs=(

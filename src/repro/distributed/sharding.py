@@ -77,14 +77,6 @@ class ShardingRules:
         constraints on the auto 'model' axis remain valid."""
         return dataclasses.replace(self, dp_axes=(), fsdp_axis=None)
 
-    def full_manual_region(self) -> "ShardingRules":
-        """Rules for a shard_map manual over *every* mesh axis (old-JAX
-        fallback, where partial-manual lowering is unavailable): no
-        constraint may mention any axis, so TP clears too."""
-        return dataclasses.replace(
-            self, dp_axes=(), fsdp_axis=None, tp_axis=None
-        )
-
 
 def make_rules(
     cfg: ModelConfig,

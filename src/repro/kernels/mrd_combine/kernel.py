@@ -5,8 +5,9 @@ The receive path of the compressed butterfly does, per stage:
 reshape-scale, add — three HBM round-trips over the gradient shard.  The
 kernel streams (x, q, scales) blocks through VMEM once.
 
-Grid: (n / bn,), bn a multiple of the 256-element quantization block so the
-scale vector tiles align (bn/256 scales per program).
+The flat vectors are viewed as ``[n/256, 256]`` rows, one quantization block
+per row, so each row's scale broadcasts along the lanes and no block is ever
+reshaped inside the kernel.  Grid: (n / bn,), ``bn/256`` rows per program.
 """
 
 from __future__ import annotations
@@ -21,11 +22,9 @@ QBLOCK = 256
 
 
 def _combine_kernel(x_ref, q_ref, s_ref, o_ref):
-    # x_ref/q_ref: [bn]; s_ref: [bn/256]; o_ref: [bn]
-    bn = x_ref.shape[0]
-    q = q_ref[...].astype(jnp.float32).reshape(bn // QBLOCK, QBLOCK)
-    deq = q * s_ref[...][:, None]
-    o_ref[...] = (x_ref[...].astype(jnp.float32) + deq.reshape(bn)).astype(o_ref.dtype)
+    # x_ref/q_ref/o_ref: [rows, 256]; s_ref: [rows, 1]
+    deq = q_ref[...].astype(jnp.float32) * s_ref[...]
+    o_ref[...] = (x_ref[...].astype(jnp.float32) + deq).astype(o_ref.dtype)
 
 
 def mrd_combine_fwd(x, q, scales, *, bn: int = 32768, interpret: bool = False):
@@ -34,15 +33,17 @@ def mrd_combine_fwd(x, q, scales, *, bn: int = 32768, interpret: bool = False):
     assert n % QBLOCK == 0, n
     bn = min(bn, n)
     assert bn % QBLOCK == 0 and n % bn == 0, (n, bn)
-    return pl.pallas_call(
+    rows, br = n // QBLOCK, bn // QBLOCK
+    out = pl.pallas_call(
         _combine_kernel,
-        grid=(n // bn,),
+        grid=(rows // br,),
         in_specs=[
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn,), lambda i: (i,)),
-            pl.BlockSpec((bn // QBLOCK,), lambda i: (i,)),
+            pl.BlockSpec((br, QBLOCK), lambda i: (i, 0)),
+            pl.BlockSpec((br, QBLOCK), lambda i: (i, 0)),
+            pl.BlockSpec((br, 1), lambda i: (i, 0)),
         ],
-        out_specs=pl.BlockSpec((bn,), lambda i: (i,)),
-        out_shape=jax.ShapeDtypeStruct((n,), x.dtype),
+        out_specs=pl.BlockSpec((br, QBLOCK), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, QBLOCK), x.dtype),
         interpret=interpret,
-    )(x, q, scales)
+    )(x.reshape(rows, QBLOCK), q.reshape(rows, QBLOCK), scales.reshape(rows, 1))
+    return out.reshape(n)

@@ -8,16 +8,15 @@ importing jax; tests and benches see the real single CPU device.
 from __future__ import annotations
 
 import jax
-
-from repro import compat
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """16x16 single-pod (256 chips) or 2x16x16 multi-pod (512 chips)."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return compat.make_mesh(
-        shape, axes, axis_types=compat.default_axis_types(len(axes)),
+    return jax.make_mesh(
+        shape, axes, axis_types=(AxisType.Auto,) * len(axes),
         devices=jax.devices()[: _prod(shape)],
     )
 
@@ -26,8 +25,8 @@ def make_nonp2_mesh():
     """Non-power-of-two demo mesh (the paper's headline case): 6 x 16 = 96
     chips — e.g. a 128-chip pod after 2 DP-slice failures, kept running by
     the MRD shifts instead of regrouping to 64."""
-    return compat.make_mesh(
-        (6, 16), ("data", "model"), axis_types=compat.default_axis_types(2),
+    return jax.make_mesh(
+        (6, 16), ("data", "model"), axis_types=(AxisType.Auto,) * 2,
         devices=jax.devices()[:96],
     )
 

@@ -54,6 +54,7 @@ import numpy as np
 
 from repro.configs import registry
 from repro.distributed import step as step_lib
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.launch.train import build_mesh
 from repro.models import transformer
 from repro.serving import (
@@ -290,9 +291,13 @@ def _continuous_main(args, cfg, mesh):
             else np.round(first.output[:4], 5).tolist())
     print(f"  request {first.id}: {first.n_tokens} tokens, "
           f"admit@{first.admit_tick} retire@{first.retire_tick}, head {tail}")
+    return eng, wl, reqs
 
 
 def main(argv=None):
+    """Run the CLI; continuous serving returns ``(engine, workload,
+    requests)`` for callers that check what was served (the per-request
+    results are ``engine.results``)."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", choices=registry.list_archs(),
                     help="model arch (required unless --workload fixedpoint_solve)")
@@ -370,6 +375,7 @@ def main(argv=None):
                          "chrome_trace[:trace.json] (load in Perfetto / "
                          "chrome://tracing)")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     if args.telemetry:
         from repro import obs
@@ -402,9 +408,8 @@ def main(argv=None):
 
     try:
         if args.continuous:
-            _continuous_main(args, cfg, mesh)
-        else:
-            _static_main(args, cfg, mesh)
+            return _continuous_main(args, cfg, mesh)
+        _static_main(args, cfg, mesh)
     finally:
         if args.telemetry:
             from repro import obs

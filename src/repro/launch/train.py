@@ -12,35 +12,45 @@ compile and fit.
 from __future__ import annotations
 
 import argparse
+import dataclasses
 import time
 
 import jax
 import numpy as np
-from jax.sharding import NamedSharding
+from jax.sharding import Mesh, NamedSharding
 
-from repro import compat, obs
+from repro import obs
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.configs import registry
 from repro.data.pipeline import DataConfig, SyntheticPipeline
 from repro.distributed import gradsync
 from repro.distributed import step as step_lib
+from repro.launch.compile_cache import enable_compilation_cache
 from repro.optim.optimizer import OptimizerConfig
 
 
 def build_mesh(dp: int, tp: int):
+    """A mesh over the first ``dp * tp`` devices in their listed order.
+
+    ``jax.make_mesh`` reorders by TPU topology and refuses a subset that is
+    not a whole sub-torus, such as 3 chips of a 2x2 host: the
+    non-power-of-two extents the paper is about need the plain order.
+    """
     axes = ("data", "model") if tp > 1 else ("data",)
     shape = (dp, tp) if tp > 1 else (dp,)
-    n = dp * tp
-    return compat.make_mesh(
-        shape, axes, axis_types=compat.default_axis_types(len(axes)),
-        devices=jax.devices()[:n],
-    )
+    devices = np.asarray(jax.devices()[: dp * tp]).reshape(shape)
+    return Mesh(devices, axes)
 
 
 def main(argv=None):
+    """Run the CLI; returns the per-step training losses."""
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", required=True, choices=registry.list_archs())
     ap.add_argument("--smoke", action="store_true", help="reduced config (CPU)")
+    ap.add_argument("--layers", type=int, default=0,
+                    help="keep only the first N layers of the config, widths "
+                         "unchanged (0 = all): a depth cut that fits the "
+                         "training state on fewer chips")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=64)
@@ -92,6 +102,7 @@ def main(argv=None):
                          "chrome_trace[:trace.json] (load in Perfetto / "
                          "chrome://tracing)")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
 
     if args.telemetry:
         from repro import obs
@@ -119,6 +130,10 @@ def _main(args):
     cfg = (
         registry.get_smoke_config(args.arch) if args.smoke else registry.get_config(args.arch)
     )
+    if args.layers:
+        if not 0 < args.layers <= cfg.n_layers:
+            raise SystemExit(f"--layers {args.layers}: {cfg.name} has {cfg.n_layers}")
+        cfg = dataclasses.replace(cfg, n_layers=args.layers)
     mesh = build_mesh(args.dp, args.tp)
     tcfg = step_lib.TrainConfig(
         microbatches=args.microbatches,
@@ -170,14 +185,19 @@ def _main(args):
             f"done ({len(trainer.resizes)} resizes, {trainer.restores} "
             f"checkpoint restores). final loss: {losses[-1]:.4f}"
         )
-        return losses[-1]
+        return [float(l) for l in losses]
 
     train_step, init_state, state_specs, rules = step_lib.make_train_step(cfg, mesh, tcfg)
 
     with mesh:
-        state = init_state(jax.random.PRNGKey(args.seed))
-        shardings = jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs(state))
-        state = jax.device_put(state, shardings)
+        # initialize straight into the shardings: an eager init would build
+        # the whole float32 train state on the first device before moving it
+        key = jax.random.PRNGKey(args.seed)
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s),
+            state_specs(jax.eval_shape(init_state, key)),
+        )
+        state = jax.jit(init_state, out_shardings=shardings)(key)
         pipe = SyntheticPipeline(
             cfg, DataConfig(batch=args.batch, seq_len=args.seq, seed=args.seed), mesh
         )
@@ -202,9 +222,11 @@ def _main(args):
         save_block = "transfer" if donate else False
 
         t0 = time.time()
+        losses = []
         for i in range(args.steps):
             with obs.span("train.step", step=i):
                 state, metrics = jstep(state, pipe.next_batch())
+            losses.append(metrics["loss"])
             if obs.enabled():
                 # the loss is a device array still in flight: the gauge
                 # stores the reference, the writer thread materializes it
@@ -241,7 +263,7 @@ def _main(args):
         if ck is not None:
             ck.save(int(state["step"]), state, extra={"data": pipe.state_dict()}, block=True)
     print("done. final loss:", float(metrics["loss"]))
-    return float(metrics["loss"])
+    return [float(l) for l in losses]
 
 
 if __name__ == "__main__":

@@ -45,7 +45,7 @@ import numpy as np
 from jax.sharding import NamedSharding
 from jax.sharding import PartitionSpec as P
 
-from repro import compat, obs
+from repro import obs
 from repro.checkpoint.checkpointer import Checkpointer
 from repro.collectives import plans
 from repro.runtime.fault_tolerance import (
@@ -84,7 +84,7 @@ def mrd_broadcast(tree, mesh, dp_axes: Sequence[str], src: int = 0,
     def local(t):
         r = jnp.zeros((), jnp.int32)
         for ax in dp_axes:
-            r = r * compat.axis_size(ax) + jax.lax.axis_index(ax)
+            r = r * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
         masked = jax.tree.map(
             lambda x: jnp.where(r == src, x, jnp.zeros_like(x)), t
         )
@@ -92,7 +92,7 @@ def mrd_broadcast(tree, mesh, dp_axes: Sequence[str], src: int = 0,
 
     rep = jax.tree.map(lambda _: P(), tree)
     return jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             local, mesh=mesh, in_specs=(rep,), out_specs=rep,
             axis_names=set(dp_axes), check_vma=False,
         )
@@ -426,8 +426,10 @@ class ElasticTrainer:
 
     def init_or_restore(self, key):
         with self.mesh:
-            state = self.init_state(key)
-            shardings = self._shardings(state)
+            # initialize straight into the shardings (no device ever holds
+            # the whole unsharded train state)
+            shardings = self._shardings(jax.eval_shape(self.init_state, key))
+            state = jax.jit(self.init_state, out_shardings=shardings)(key)
             latest = self.ck.latest_step() if self.ck else None
             if latest is not None:
                 # params + step survive topology changes; optimizer moments

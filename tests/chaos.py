@@ -161,13 +161,13 @@ class ChaosScript:
 
 
 def _mesh_from_ids(device_ids):
-    from repro import compat
+    from jax.sharding import AxisType
 
     by_id = {d.id: d for d in jax.devices()}
     devs = [by_id[i] for i in device_ids]
-    return compat.make_mesh(
+    return jax.make_mesh(
         (len(devs),), ("data",), devices=devs,
-        axis_types=compat.default_axis_types(1),
+        axis_types=(AxisType.Auto,),
     )
 
 
@@ -204,11 +204,12 @@ def oracle_replay(
     mesh = _mesh_from_ids(initial_device_ids)
     train_step, init_state, state_specs, _ = step_lib.make_train_step(cfg, mesh, tcfg)
     with mesh:
-        state = init_state(key)
-        state = jax.device_put(
-            state,
-            jax.tree.map(lambda s: NamedSharding(mesh, s), state_specs(state)),
+        # the same sharded jitted init as ElasticTrainer.init_or_restore
+        shardings = jax.tree.map(
+            lambda s: NamedSharding(mesh, s),
+            state_specs(jax.eval_shape(init_state, key)),
         )
+        state = jax.jit(init_state, out_shardings=shardings)(key)
     pipe = SyntheticPipeline(cfg, dcfg, mesh)
     jit_step = jax.jit(train_step)
     losses = []

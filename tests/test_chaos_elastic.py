@@ -286,7 +286,7 @@ _PRELUDE = textwrap.dedent(
     import sys
     sys.path.insert(0, {here!r})
     import jax, jax.numpy as jnp, numpy as np
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.configs import registry
     from repro.data.pipeline import DataConfig, SyntheticPipeline
     from repro.distributed import step as step_lib
@@ -308,10 +308,10 @@ _PRELUDE = textwrap.dedent(
             **kw)
 
     def run_chaos(tcfg, dcfg, dev_ids, script, steps, policy, hb=None):
-        mesh = compat.make_mesh(
+        mesh = jax.make_mesh(
             (len(dev_ids),), ("data",),
             devices=[jax.devices()[i] for i in dev_ids],
-            axis_types=compat.default_axis_types(1))
+            axis_types=(AxisType.Auto,))
         tr = ElasticTrainer(
             mesh, (cfg, tcfg),
             pipe_factory=lambda m: SyntheticPipeline(cfg, dcfg, m),

@@ -25,9 +25,9 @@ act.
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
 from chaos import ChaosScript, Join, Kill, Stall, Unstall
-from repro import compat
 from repro.configs import registry
 from repro.runtime import ElasticServeController, HeartbeatConfig, StepClock
 from repro.serving import Request, ServeConfig, ServeEngine, make_workload
@@ -36,9 +36,9 @@ import jax
 
 
 def _mesh():
-    return compat.make_mesh(
+    return jax.make_mesh(
         (1,), ("data",), devices=jax.devices()[:1],
-        axis_types=compat.default_axis_types(1),
+        axis_types=(AxisType.Auto,),
     )
 
 

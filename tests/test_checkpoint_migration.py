@@ -36,11 +36,11 @@ def _real_state():
         monitor=True, monitor_mode="exact", monitor_threshold=1e-6,
         optimizer=OptimizerConfig(lr=1e-3, schedule="const", warmup_steps=0),
     )
-    from repro import compat
+    from jax.sharding import AxisType
 
-    mesh = compat.make_mesh(
+    mesh = jax.make_mesh(
         (1,), ("data",), devices=jax.devices()[:1],
-        axis_types=compat.default_axis_types(1),
+        axis_types=(AxisType.Auto,),
     )
     _, init_state, _, _ = step_lib.make_train_step(cfg, mesh, tcfg)
     with mesh:

@@ -229,7 +229,7 @@ _DEVICE_SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=17"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.collectives import plans
     from repro.collectives.schedules import pivot
 
@@ -238,11 +238,11 @@ _DEVICE_SCRIPT = textwrap.dedent(
     def run_device(plan_dev, x, mesh):
         def local(v):
             return plan_dev.run(v[0])[None]
-        return jax.jit(compat.shard_map(
-            local, mesh=mesh, in_specs=P("r"), out_specs=P("r")))(x)
+        return jax.jit(jax.shard_map(
+            local, mesh=mesh, in_specs=P("r"), out_specs=P("r"), check_vma=False))(x)
 
     for p in [2, 3, 4, 5, 6, 7, 8, 9, 10, 11, 12, 13, 16, 17]:
-        mesh = compat.make_mesh((p,), ("r",), devices=jax.devices()[:p])
+        mesh = jax.make_mesh((p,), ("r",), devices=jax.devices()[:p], axis_types=(AxisType.Auto,))
         for schedule in ["mrd", "rabenseifner"]:
             for op in ["sum", "max", "min"]:
                 if schedule == "rabenseifner" and op != "sum":
@@ -260,7 +260,7 @@ _DEVICE_SCRIPT = textwrap.dedent(
 
     # int8 transform parity on a subset (wire format must be identical too)
     for p in [3, 6, 8, 13]:
-        mesh = compat.make_mesh((p,), ("r",), devices=jax.devices()[:p])
+        mesh = jax.make_mesh((p,), ("r",), devices=jax.devices()[:p], axis_types=(AxisType.Auto,))
         sim = plans.reduce_scatter_plan(p=p, transform="int8")
         dev = plans.reduce_scatter_plan(axes=("r",), transform="int8")
         n = sim.pad_quantum()

@@ -3,7 +3,8 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.collectives import compression as C
 

@@ -55,7 +55,7 @@ _ELASTIC = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
 
     from repro.configs import registry
     from repro.checkpoint.checkpointer import Checkpointer
@@ -72,8 +72,8 @@ _ELASTIC = textwrap.dedent(
     ckdir = tempfile.mkdtemp()
 
     # ---- phase 1: dp=4 ----
-    mesh4 = compat.make_mesh((4,), ("data",), devices=jax.devices()[:4],
-                          axis_types=compat.default_axis_types(1))
+    mesh4 = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4],
+                          axis_types=(AxisType.Auto,))
     step4, init4, specs4, _ = step_lib.make_train_step(cfg, mesh4, tcfg)
     with mesh4:
         state = init4(jax.random.PRNGKey(0))

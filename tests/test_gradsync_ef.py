@@ -94,12 +94,12 @@ def test_trainconfig_wires_error_feedback_state():
     """The compressed strategy carries opt['ef'] iff error feedback is on
     (builder-level check; the multi-device trajectory runs in
     tests/test_train_distributed.py)."""
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.configs import registry
     from repro.distributed import step as step_lib
 
     cfg = registry.get_smoke_config("llama3.2-1b")
-    mesh = compat.make_mesh((1,), ("data",), devices=jax.devices()[:1])
+    mesh = jax.make_mesh((1,), ("data",), devices=jax.devices()[:1], axis_types=(AxisType.Auto,))
     for ef_on in (True, False):
         tcfg = step_lib.TrainConfig(
             grad_sync="compressed", monitor=False, error_feedback=ef_on

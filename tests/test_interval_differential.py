@@ -23,7 +23,7 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=9"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.asynchrony.protocols import (
         RES_INIT, ConvergenceMonitor, get_protocol)
     from repro.collectives import plans
@@ -33,7 +33,7 @@ _SCRIPT = textwrap.dedent(
     rng = np.random.default_rng(0)
 
     for p in range(2, 10):
-        mesh = compat.make_mesh((p,), ("r",), devices=jax.devices()[:p])
+        mesh = jax.make_mesh((p,), ("r",), devices=jax.devices()[:p], axis_types=(AxisType.Auto,))
         mon = ConvergenceMonitor(axis_name="r", threshold=THR,
                                  mode="interval", window=W)
         cycle = plans.allreduce_plan(schedule="mrd", p=p, op="max").cycle_length()
@@ -52,7 +52,7 @@ _SCRIPT = textwrap.dedent(
             return jax.tree.map(lambda x: x[None], new), done[None], val[None]
 
         rspec = jax.tree.map(lambda _: P("r"), rows)
-        dev_step = jax.jit(compat.shard_map(
+        dev_step = jax.jit(jax.shard_map(
             local, mesh=mesh,
             in_specs=(rspec, P("r"), P()),
             out_specs=(rspec, P("r"), P("r")),

@@ -14,7 +14,7 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding
-    from repro import compat
+    from jax.sharding import AxisType
 
     from repro.configs import registry
     from repro.data.pipeline import DataConfig, SyntheticPipeline
@@ -22,7 +22,7 @@ _SCRIPT = textwrap.dedent(
     from repro.optim.optimizer import OptimizerConfig
 
     cfg = registry.get_smoke_config("llama3.2-1b")
-    mesh = compat.make_mesh((4,), ("data",), axis_types=compat.default_axis_types(1))
+    mesh = jax.make_mesh((4,), ("data",), axis_types=(AxisType.Auto,))
     tcfg = step_lib.TrainConfig(
         microbatches=1, remat="none", grad_sync="local_sgd", monitor=False,
         local_sync_every=4,

@@ -16,16 +16,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 from jax.sharding import PartitionSpec as P
 
-from repro import compat
 from repro.core.detection import ConvergenceMonitor
 
 
 @pytest.mark.parametrize("mode", ["inexact", "exact"])
 def test_monitor_tuple_axes_single_device(mode):
     """Tuple axis_name must work (this used to raise on lax.axis_size)."""
-    mesh = compat.make_mesh((1, 1), ("pod", "data"), devices=jax.devices()[:1])
+    mesh = jax.make_mesh((1, 1), ("pod", "data"), devices=jax.devices()[:1], axis_types=(AxisType.Auto,) * 2)
     mon = ConvergenceMonitor(
         axis_name=("pod", "data"), threshold=1e-3, mode=mode
     )
@@ -43,11 +43,12 @@ def test_monitor_tuple_axes_single_device(mode):
 
     series = jnp.geomspace(1.0, 1e-6, 12, dtype=jnp.float32)
     dones, vals = jax.jit(
-        compat.shard_map(
+        jax.shard_map(
             lambda s: run(s[0]),
             mesh=mesh,
             in_specs=P(("pod", "data")),
             out_specs=(P(("pod", "data")), P(("pod", "data"))),
+            check_vma=False,
         )
     )(series[None])
     assert bool(np.asarray(dones)[0, -1]), "monitor never detected"
@@ -71,10 +72,10 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.core.detection import ConvergenceMonitor
 
-    mesh = compat.make_mesh((2, 2), ("pod", "data"), devices=jax.devices()[:4])
+    mesh = jax.make_mesh((2, 2), ("pod", "data"), devices=jax.devices()[:4], axis_types=(AxisType.Auto,) * 2)
     mon = ConvergenceMonitor(axis_name=("pod", "data"), threshold=1e-3,
                              mode="exact")
 
@@ -95,10 +96,10 @@ _SCRIPT = textwrap.dedent(
 
     ranks = jnp.arange(4, dtype=jnp.float32).reshape(2, 2) + 1.0
     series = ranks[..., None] * base  # [2, 2, steps]
-    dones, vals = jax.jit(compat.shard_map(
+    dones, vals = jax.jit(jax.shard_map(
         lambda s: run(s[0]), mesh=mesh,
         in_specs=P(("pod", "data")),
-        out_specs=(P(("pod", "data")), P(("pod", "data")))))(
+        out_specs=(P(("pod", "data")), P(("pod", "data"))), check_vma=False))(
             series.reshape(4, steps))
     dones = np.asarray(dones).reshape(4, steps)
     vals = np.asarray(vals).reshape(4, steps)

@@ -17,16 +17,16 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.core import mrd, nonblocking, detection
     from repro.core.topology import pivot
 
     def shard_map(f, *, mesh, in_specs, out_specs):
-        return compat.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs)
+        return jax.shard_map(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_vma=False)
 
     def mesh_for(p):
-        return compat.make_mesh((p,), ("r",), devices=jax.devices()[:p],
-                                axis_types=compat.default_axis_types(1))
+        return jax.make_mesh((p,), ("r",), devices=jax.devices()[:p],
+                                axis_types=(AxisType.Auto,))
 
     rng = np.random.default_rng(0)
 
@@ -86,8 +86,8 @@ _SCRIPT = textwrap.dedent(
     print("tree-bucketed device==sim OK")
 
     # --- hierarchical allreduce over a 2D mesh (pod-aware) ---
-    mesh2 = compat.make_mesh((2, 4), ("pod", "data"), devices=jax.devices()[:8],
-                          axis_types=compat.default_axis_types(2))
+    mesh2 = jax.make_mesh((2, 4), ("pod", "data"), devices=jax.devices()[:8],
+                          axis_types=(AxisType.Auto,) * 2)
     n = 8
     x = jnp.asarray(rng.standard_normal((8, n)).astype(np.float32))
     def hier(v):

@@ -214,16 +214,16 @@ _SCRIPT = textwrap.dedent(
     import jax.numpy as jnp
     import numpy as np
 
-    from repro import compat
+    from jax.sharding import AxisType
     from repro.configs import registry
     from repro.data.pipeline import DataConfig, SyntheticPipeline
     from repro.distributed import step as step_lib
     from repro.optim.optimizer import OptimizerConfig
 
     def run(mode, dp, overlap, cfg, steps=3):
-        mesh = compat.make_mesh(
+        mesh = jax.make_mesh(
             (dp,), ("data",),
-            axis_types=compat.default_axis_types(1),
+            axis_types=(AxisType.Auto,),
             devices=jax.devices()[:dp],
         )
         tcfg = step_lib.TrainConfig(

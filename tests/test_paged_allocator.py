@@ -19,7 +19,8 @@ shape, and clean rollback on exhaustion.
 import numpy as np
 import pytest
 
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.serving.paged import BlockAllocator, PagedDecodePool
 

@@ -4,7 +4,8 @@ launcher helper properties."""
 import jax
 import numpy as np
 import pytest
-from hypothesis_compat import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import async_engine as ae
 from repro.core import mrd, solvers

@@ -56,15 +56,15 @@ _ELASTIC = textwrap.dedent(
     from repro.distributed import step as step_lib
     from repro.optim.optimizer import OptimizerConfig
     from repro.runtime.elastic import ElasticConfig, ElasticTrainer
-    from repro import compat
+    from jax.sharding import AxisType
 
     cfg = registry.get_smoke_config("llama3.2-1b")
     tcfg = step_lib.TrainConfig(
         microbatches=1, remat="none", grad_sync="mrd_leaf", monitor=False,
         optimizer=OptimizerConfig(lr=5e-3, schedule="const", warmup_steps=0))
 
-    mesh = compat.make_mesh((4,), ("data",), devices=jax.devices()[:4],
-                         axis_types=compat.default_axis_types(1))
+    mesh = jax.make_mesh((4,), ("data",), devices=jax.devices()[:4],
+                         axis_types=(AxisType.Auto,))
     trainer = ElasticTrainer(
         mesh,
         step_fn_factory=lambda m: step_lib.make_train_step(cfg, m, tcfg),

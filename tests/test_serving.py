@@ -23,8 +23,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
-from repro import compat
 from repro.configs import registry
 from repro.core import topology
 from repro.distributed import step as step_lib
@@ -45,9 +45,9 @@ from repro.serving.termination import make_signals
 
 
 def _mesh():
-    return compat.make_mesh(
+    return jax.make_mesh(
         (1,), ("data",), devices=jax.devices()[:1],
-        axis_types=compat.default_axis_types(1),
+        axis_types=(AxisType.Auto,),
     )
 
 

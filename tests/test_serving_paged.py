@@ -24,8 +24,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
+from jax.sharding import AxisType
 
-from repro import compat
 from repro.configs import registry
 from repro.distributed import step as step_lib
 from repro.models import transformer
@@ -39,9 +39,9 @@ from repro.serving import (
 
 
 def _mesh(n=1):
-    return compat.make_mesh(
+    return jax.make_mesh(
         (n,), ("data",), devices=jax.devices()[:n],
-        axis_types=compat.default_axis_types(1),
+        axis_types=(AxisType.Auto,),
     )
 
 

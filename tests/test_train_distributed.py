@@ -14,7 +14,7 @@ _SCRIPT = textwrap.dedent(
     os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
     import jax, jax.numpy as jnp, numpy as np
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro import compat
+    from jax.sharding import AxisType
     import dataclasses
 
     from repro.configs import registry
@@ -26,9 +26,9 @@ _SCRIPT = textwrap.dedent(
 
     def run_mode(mesh_shape, axis_names, grad_sync, steps=6, monitor=True, ndev=8,
                  bucket_bytes=32 * 2**20):
-        mesh = compat.make_mesh(mesh_shape, axis_names,
+        mesh = jax.make_mesh(mesh_shape, axis_names,
                                 devices=jax.devices()[:ndev],
-                                axis_types=compat.default_axis_types(len(axis_names)))
+                                axis_types=(AxisType.Auto,) * len(axis_names))
         tcfg = step_lib.TrainConfig(
             microbatches=2, remat="none", grad_sync=grad_sync, monitor=monitor,
             monitor_threshold=1e-6, bucket_bytes=bucket_bytes,
@@ -82,8 +82,8 @@ _SCRIPT = textwrap.dedent(
     # --- 5. monitor fires when threshold is lenient ---
     _, _, metrics = run_mode((4, 2), ("data", "model"), "gspmd", steps=8)
     # threshold 1e-6 won't fire in 8 steps; re-run with a huge threshold
-    mesh = compat.make_mesh((4, 2), ("data", "model"),
-                            axis_types=compat.default_axis_types(2))
+    mesh = jax.make_mesh((4, 2), ("data", "model"),
+                            axis_types=(AxisType.Auto,) * 2)
     tcfg = step_lib.TrainConfig(
         microbatches=1, remat="none", grad_sync="gspmd", monitor=True,
         monitor_threshold=100.0,
