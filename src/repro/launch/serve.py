@@ -269,7 +269,8 @@ def _continuous_main(args, cfg, mesh):
     print(f"  throughput {s['throughput_tok_s']:.1f} tok/s | occupancy "
           f"{s['occupancy']:.2f} | converged {s['converged']}/{s['completed']}")
     print(f"  TTFT p50/p95 {s['ttft_p50_ms']:.1f}/{s['ttft_p95_ms']:.1f} ms | "
-          f"TPOT p50/p95 {s['tpot_p50_ms']:.2f}/{s['tpot_p95_ms']:.2f} ms")
+          f"TPOT p50/p95 {s['tpot_p50_ms']:.2f}/{s['tpot_p95_ms']:.2f} ms | "
+          f"queue p50/p95 {s['queue_p50_ms']:.1f}/{s['queue_p95_ms']:.1f} ms")
     if s["sla_total"]:
         print(f"  SLA {s['sla_met']}/{s['sla_total']} met | goodput "
               f"{s['goodput_ok']} ({s['goodput_per_ktick']:.1f}/ktick) | "

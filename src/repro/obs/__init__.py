@@ -36,6 +36,8 @@ from __future__ import annotations
 
 from typing import Any, Dict, Optional
 
+import jax
+
 from .metrics import Counter, Gauge, Histogram, MetricsRegistry
 from .sinks import SINKS, Sink, get_sink, parse_spec, register_sink
 from .tracing import _NULL_SPAN, Tracer
@@ -67,9 +69,17 @@ __all__ = [
 ]
 
 
+# jax.monitoring's event for one backend compile (or persistent-cache load)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+
+
 class Telemetry:
     """A registry + tracer + sink bundle. One process-global instance lives
-    in this module; tests construct their own to stay isolated."""
+    in this module; tests construct their own to stay isolated.
+
+    While configured it listens to ``jax.monitoring`` and records every
+    backend compile as a ``jax.compile`` span (back-dated by the reported
+    duration), so a compile that stalls the host names itself in a trace."""
 
     def __init__(
         self,
@@ -81,6 +91,22 @@ class Telemetry:
         self.tracer = tracer or Tracer()
         self.sink = sink
         self.enabled = False
+        self._listening = False
+
+    def _on_duration(self, event: str, duration_secs: float, **kwargs) -> None:
+        if event == BACKEND_COMPILE_EVENT and self.enabled:
+            self.tracer.complete(
+                "jax.compile", int(duration_secs * 1e9),
+                fun=kwargs.get("fun_name", ""),
+            )
+
+    def _listen(self, on: bool) -> None:
+        if on != self._listening:
+            if on:
+                jax.monitoring.register_event_duration_secs_listener(self._on_duration)
+            else:
+                jax.monitoring.unregister_event_duration_listener(self._on_duration)
+            self._listening = on
 
     def configure(self, spec: str = "null", background: bool = True) -> "Telemetry":
         """Select a sink by spec and enable recording.  ``background=True``
@@ -89,6 +115,7 @@ class Telemetry:
         self.sink = get_sink(spec)
         self.enabled = True
         self.tracer.enabled = True
+        self._listen(True)
         if background:
             self.registry.start(self.sink)
         else:
@@ -104,6 +131,7 @@ class Telemetry:
         out = self.summary()
         self.enabled = False
         self.tracer.enabled = False
+        self._listen(False)
         return out
 
     def summary(self) -> Dict[str, Any]:
@@ -185,6 +213,7 @@ def reset() -> None:
         _GLOBAL.registry.stop()
     except Exception:
         pass
+    _GLOBAL._listen(False)
     _GLOBAL = Telemetry()
 
 

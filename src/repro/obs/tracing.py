@@ -16,6 +16,14 @@ exported trace, so per-stage message counts, byte volumes, resize extents
 etc. are attached to the event that produced them rather than logged out
 of band.
 
+While enabled, each span and instant also opens a
+``jax.profiler.TraceAnnotation`` of the same name and args, so under an
+active ``jax.profiler`` session the program's spans sit on the trace's host
+plane, on the same clock as the device's operations.  A span reported
+after the fact (:meth:`Tracer.complete`, e.g. a compile's duration) lands
+there as a marker at its end that carries its length as the ``dur_ns``
+arg.
+
 Export is :meth:`Tracer.chrome_trace`: the Trace Event Format JSON object
 (``{"traceEvents": [...]}``) that ``chrome://tracing`` and Perfetto load
 directly.  Complete events use phase ``"X"`` with microsecond ``ts``/
@@ -33,6 +41,8 @@ import threading
 import time
 from contextlib import contextmanager
 from typing import Any, Dict, List, Optional
+
+from jax.profiler import TraceAnnotation
 
 _PH_SPAN = "X"
 _PH_INSTANT = "i"
@@ -76,18 +86,36 @@ class Tracer:
         if not self.enabled:
             yield None
             return
+        ann = TraceAnnotation(name, **args)
+        ann.__enter__()
+        n_entry = len(args)
         t0 = time.perf_counter_ns()
         try:
             yield args
         finally:
             dur = time.perf_counter_ns() - t0
+            if len(args) > n_entry:  # args the body attached at exit
+                ann.set_metadata(**dict(list(args.items())[n_entry:]))
+            ann.__exit__(None, None, None)
             self._spans += 1
             self._push((_PH_SPAN, name, t0, dur, self._tid(), args or None))
+
+    def complete(self, name: str, dur_ns: int, **args) -> None:
+        """Record a span that ended now and lasted ``dur_ns``."""
+        if not self.enabled:
+            return
+        with TraceAnnotation(name, dur_ns=dur_ns, **args):
+            pass
+        self._spans += 1
+        t1 = time.perf_counter_ns()
+        self._push((_PH_SPAN, name, t1 - dur_ns, dur_ns, self._tid(), args or None))
 
     def instant(self, name: str, **args) -> None:
         """Record a zero-duration marker event."""
         if not self.enabled:
             return
+        with TraceAnnotation(name, **args):
+            pass
         self._instants += 1
         self._push(
             (_PH_INSTANT, name, time.perf_counter_ns(), 0, self._tid(), args or None)

@@ -69,6 +69,7 @@ class RequestResult:
     converged: bool  # False only for budget-forced fixed-point retirement
     ttft_s: float
     tpot_s: float  # NaN for n_tokens <= 1 (no inter-token interval exists)
+    queued_s: float  # admission start - submission (host clock)
     retries: int = 0  # capacity-forced requeues this request went through
     tenant: str = ""
     sla: Optional[int] = None
@@ -180,10 +181,15 @@ class ServeEngine:
         term, tcfg = self.term, self.tcfg
         cap_fn = getattr(workload, "capacity_mask", None)
 
+        # layer scopes, carried into every op's ``op_name`` metadata (a
+        # profile's op view groups device time by them): ``pool.step`` the
+        # workload's step, ``termination`` the protocol tick, and inside it
+        # ``termination.agree`` the MRD stage advance
         def _fused(params, wstate, tstate, ctrl, tick):
-            wstate, tokens, residual = workload.device_step(
-                params, wstate, ctrl["active"], tick
-            )
+            with jax.named_scope("pool.step"):
+                wstate, tokens, residual = workload.device_step(
+                    params, wstate, ctrl["active"], tick
+                )
             new_tokens = jnp.where(
                 ctrl["active"], ctrl["new_tokens"] + 1, ctrl["new_tokens"]
             )
@@ -195,7 +201,8 @@ class ServeEngine:
                 active=ctrl["active"], admit_tick=ctrl["admit_tick"],
                 tick=tick, residual=residual,
             )
-            tstate, retire = term.tick(tstate, sig, tcfg)
+            with jax.named_scope("termination"):
+                tstate, retire = term.tick(tstate, sig, tcfg)
             if certifying:
                 # iteration budget exhausted before the protocol certified
                 forced = ctrl["active"] & (new_tokens >= ctrl["max_new"]) & ~retire
@@ -532,9 +539,15 @@ class ServeEngine:
                 self.queue.remove(req)
                 if self._quotas:
                     inflight[req.tenant] = inflight.get(req.tenant, 0) + 1
-                t0 = time.perf_counter()
-                self.workload.admit(req, slot, now)
-                self._after_admit(req, slot, now, t0)
+                t0 = req._t_admit = time.perf_counter()
+                with obs.span("serve.admit.request") as rsp:
+                    if rsp is not None:
+                        rsp.update(slot=slot, request=req.id, queued_ms=1e3 * (
+                            t0 - getattr(req, "_t_submit", t0)))
+                        if req.prompt is not None:
+                            rsp["prompt_len"] = len(req.prompt)
+                    self.workload.admit(req, slot, now)
+                    self._after_admit(req, slot, now, t0)
                 n_admitted += 1
             if sp is not None:
                 sp["n_admitted"] = n_admitted
@@ -553,15 +566,16 @@ class ServeEngine:
             return np.zeros((self.slots,), bool)
 
         if self._ctrl_dirty:
-            ctrl = {
-                "active": jnp.asarray(self._active),
-                "new_tokens": jnp.asarray(self._new_tokens),
-                "admit_tick": jnp.asarray(self._admit_tick),
-                "eos": jnp.asarray(self._eos),
-                "max_new": jnp.asarray(self._max_new),
-                "eps": jnp.asarray(self._eps),
-            }
-            self._ctrl = self._commit(ctrl)
+            with obs.span("serve.ctrl"):
+                ctrl = {
+                    "active": jnp.asarray(self._active),
+                    "new_tokens": jnp.asarray(self._new_tokens),
+                    "admit_tick": jnp.asarray(self._admit_tick),
+                    "eos": jnp.asarray(self._eos),
+                    "max_new": jnp.asarray(self._max_new),
+                    "eps": jnp.asarray(self._eps),
+                }
+                self._ctrl = self._commit(ctrl)
             self._ctrl_dirty = False
 
         # 2-3. pool steps + termination ticks, one fused dispatch running up
@@ -585,45 +599,49 @@ class ServeEngine:
             self.workload.wstate = final["wstate"]
             self.tstate = final["tstate"]
             self._ctrl = final["ctrl"]
-            n_ticks = int(final["i"])
+            with obs.span("serve.wait"):  # the host waits on the device
+                n_ticks = int(final["i"])
             # convert whole buffers, slice on host: device-side slicing at a
             # data-dependent length would compile one kernel per distinct
             # length
             active_buf = np.asarray(final["active_buf"])[:n_ticks]
             tokens_buf = np.asarray(final["tokens_buf"])[:n_ticks]
 
-            for k in range(n_ticks):
-                act = active_buf[k]
-                self._new_tokens[act] += 1
-                self.workload.collect_tick(tokens_buf[k], act)
-                self._occupancy_sum += float(act.sum()) / self.slots
-                self._occupancy_ticks += 1
+            with obs.span("serve.collect"):
+                for k in range(n_ticks):
+                    act = active_buf[k]
+                    self._new_tokens[act] += 1
+                    self.workload.collect_tick(tokens_buf[k], act)
+                    self._occupancy_sum += float(act.sum()) / self.slots
+                    self._occupancy_ticks += 1
             if sp is not None:
                 sp["n_ticks"] = n_ticks
 
         # 4. retire: by construction only the last executed tick can retire
         # (the device loop exits right after it)
         last = n_ticks - 1
-        retire = np.asarray(final["retire_buf"])[last]
-        forced = np.asarray(final["forced_buf"])[last]
-        at_cap = np.asarray(final["cap_buf"])[last]
+        with obs.span("serve.flags"):  # the last tick's flags, off the device
+            retire = np.asarray(final["retire_buf"])[last]
+            forced = np.asarray(final["forced_buf"])[last]
+            at_cap = np.asarray(final["cap_buf"])[last]
         out_mask = retire | forced
         if out_mask.any():
-            self._active[out_mask] = False
-            certified = np.asarray(self.tstate["certified"])
-            t_done = time.perf_counter()
-            for slot in np.nonzero(out_mask)[0]:
-                req = self.slot_req[slot]
-                obs.instant(
-                    "serve.retire",
-                    slot=int(slot),
-                    tick=now + last,
-                    forced=bool(forced[slot]),
-                    request=req.id if req is not None else None,
-                )
-                self._collect(int(slot), now + last, certified,
-                              bool(forced[slot]), t_done,
-                              at_capacity=bool(at_cap[slot]))
+            slots = np.nonzero(out_mask)[0]
+            with obs.span("serve.retire") as sp:
+                if sp is not None:
+                    sp.update(
+                        tick=now + last, n=len(slots),
+                        forced=int(forced[slots].sum()), slots=slots.tolist(),
+                        requests=[getattr(self.slot_req[s], "id", None)
+                                  for s in slots],
+                    )
+                self._active[out_mask] = False
+                certified = np.asarray(self.tstate["certified"])
+                t_done = time.perf_counter()
+                for slot in slots:
+                    self._collect(int(slot), now + last, certified,
+                                  bool(forced[slot]), t_done,
+                                  at_capacity=bool(at_cap[slot]))
         self.tick = now + n_ticks
         self._replica_ticks += n_ticks * self.dp
         self._t_last = time.perf_counter()
@@ -682,6 +700,7 @@ class ServeEngine:
             admit_tick=admit_tick, retire_tick=now,
             n_tokens=n_tok, certified=cert,
             converged=not was_forced, ttft_s=ttft, tpot_s=tpot,
+            queued_s=req._t_admit - self._t_queue[slot],
             retries=getattr(req, "_retries", 0),
             tenant=req.tenant, sla=req.sla, sla_met=sla_met,
         )
@@ -755,10 +774,12 @@ def _pct_ms(seconds: np.ndarray, q: float) -> float:
 def _latency_percentiles(res) -> Dict[str, float]:
     ttft = np.asarray([r.ttft_s for r in res], np.float64)
     tpot = np.asarray([r.tpot_s for r in res], np.float64)
+    queue = np.asarray([r.queued_s for r in res], np.float64)
     out = {}
     for q in (50, 95, 99):
         out[f"ttft_p{q}_ms"] = _pct_ms(ttft, q)
         out[f"tpot_p{q}_ms"] = _pct_ms(tpot, q)
+        out[f"queue_p{q}_ms"] = _pct_ms(queue, q)
     return out
 
 

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.distributed import serve as dserve
 from repro.models import transformer
 from repro.models.config import ModelConfig
@@ -389,22 +390,26 @@ class PagedDecodePool:
             )
         if self.slot_blocks[slot]:
             self.release_slot(slot)  # defensive: engine releases at retire
-        blocks, write_mask, shared = self._plan_blocks(prompt, plen, int(max_new))
-        self.slot_blocks[slot] = blocks
-        self.prefix_saved_blocks += shared
-        table_row = np.zeros((self.blocks_per_slot,), np.int32)
-        table_row[: len(blocks)] = blocks
-        mask = np.zeros((self.blocks_per_slot,), bool)
-        mask[: len(blocks)] = write_mask
-        padded = np.zeros((self.max_prompt_len,), np.int32)
-        padded[:plen] = prompt
-        with self.mesh:
-            self.state = self._jadmit(
-                params, self.state, jnp.asarray(padded), jnp.int32(plen),
-                jnp.int32(slot), jnp.asarray(table_row), jnp.asarray(mask),
-                jnp.int32(len(blocks) * self.block_size),
+        with obs.span("paged.plan"):
+            blocks, write_mask, shared = self._plan_blocks(
+                prompt, plen, int(max_new)
             )
-        return int(self.state["tokens"][slot])
+            self.slot_blocks[slot] = blocks
+            self.prefix_saved_blocks += shared
+            table_row = np.zeros((self.blocks_per_slot,), np.int32)
+            table_row[: len(blocks)] = blocks
+            mask = np.zeros((self.blocks_per_slot,), bool)
+            mask[: len(blocks)] = write_mask
+            padded = np.zeros((self.max_prompt_len,), np.int32)
+            padded[:plen] = prompt
+        with obs.span("paged.prefill"):  # the dispatch and its first token
+            with self.mesh:
+                self.state = self._jadmit(
+                    params, self.state, jnp.asarray(padded), jnp.int32(plen),
+                    jnp.int32(slot), jnp.asarray(table_row), jnp.asarray(mask),
+                    jnp.int32(len(blocks) * self.block_size),
+                )
+            return int(self.state["tokens"][slot])
 
     def release_slot(self, slot: int) -> None:
         """Return the slot's blocks to the allocator (slot recycling)."""

@@ -114,7 +114,8 @@ class _TerminationBase:
         plan = self._plan(cfg)
         starting = st["nb"]["stage"] == 0
         t_latch = jnp.where(starting, sig["tick"], st["t_latch"])
-        nb = plan.step(st["nb"], contribution)
+        with jax.named_scope("termination.agree"):
+            nb = plan.step(st["nb"], contribution)
         return nb, t_latch, nb["flag"], nb["result"][0]
 
     def _guard(self, sig, t_latch):

@@ -343,6 +343,117 @@ def test_serve_engine_summary_has_telemetry_subdict():
     assert counts["serve.retire"] == 1
 
 
+def _host_events(tmp_path, fn):
+    """Run ``fn`` under a jax.profiler session; the host planes' events."""
+    import glob
+    import os
+
+    import jax
+    from jax.profiler import ProfileData
+
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    path = glob.glob(os.path.join(str(tmp_path), "**", "*.xplane.pb"),
+                     recursive=True)[-1]
+    pd = ProfileData.from_file(path)
+    return [(e.name, e.duration_ns, dict(e.stats))
+            for pl in pd.planes if pl.name.startswith("/host:")
+            for line in pl.lines for e in line.events]
+
+
+def test_enabled_span_lands_on_the_profilers_host_plane(tmp_path):
+    obs.configure("null", background=False)
+
+    def body():
+        with obs.span("serve.tick", tick=3) as sp:
+            sp["n_ticks"] = 2  # attached at exit: still on the trace
+        obs.instant("serve.mark", k=1)
+
+    evs = {n: (d, st) for n, d, st in _host_events(tmp_path, body)}
+    assert evs["serve.tick"][1] == {"tick": 3, "n_ticks": 2}
+    assert evs["serve.mark"][1] == {"k": 1}
+    assert obs.telemetry().tracer.counts("serve.") == {
+        "serve.tick": 1, "serve.mark": 1}
+
+
+def test_disabled_span_records_nothing_and_opens_no_annotation(tmp_path):
+    from repro.obs.tracing import _NULL_SPAN
+
+    assert obs.span("never.span", a=1) is _NULL_SPAN
+
+    def body():
+        with obs.span("never.span", a=1):
+            obs.instant("never.instant")
+
+    names = {n for n, _, _ in _host_events(tmp_path, body)}
+    assert not names & {"never.span", "never.instant"}
+    assert obs.telemetry().tracer.summary()["recorded"] == 0
+
+
+def test_fresh_jit_under_telemetry_yields_one_compile_span(tmp_path):
+    import jax
+
+    x = jnp.ones((7,), jnp.float32)
+    obs.configure("null", background=False)
+    evs = _host_events(
+        tmp_path, lambda: jax.jit(lambda v: v * 3.0 + 1.0)(x).block_until_ready())
+    spans = [e for e in obs.telemetry().tracer.events() if e[1] == "jax.compile"]
+    assert len(spans) == 1
+    ph, _, ts, dur, _, args = spans[0]
+    assert ph == "X" and dur > 0 and "lambda" in args["fun"]
+    # on the profiler's trace: a marker at its end that carries its length
+    [(d, st)] = [(d, st) for n, d, st in evs if n == "jax.compile"]
+    assert d < st["dur_ns"] == dur
+    obs.shutdown()  # the listener goes with the session
+    jax.jit(lambda v: v - 2.0)(x).block_until_ready()
+    assert obs.telemetry().tracer.counts("jax.") == {"jax.compile": 1}
+
+
+def test_fused_tick_has_layer_scopes():
+    from repro.serving import Request, ServeConfig, ServeEngine, make_workload
+
+    wl = make_workload("fixedpoint_solve", solver="d_iteration", n=24, slots=2, dp=3)
+    eng = ServeEngine(wl, ServeConfig(termination="residual_interval", dp=3))
+    eng.submit(Request(id=0, max_new=50))
+    eng.step()
+    text = eng._jfused.lower(
+        wl.params, wl.wstate, eng.tstate, eng._ctrl, jnp.int32(0), jnp.int32(4)
+    ).compile().as_text()
+    body = 'op_name="jit(_fused_loop)/while/body/'
+    assert body + "pool.step/" in text
+    assert body + "termination/" in text
+    assert body + "termination/termination.agree/" in text
+
+
+def test_admit_request_span_carries_queue_wait():
+    from repro.serving import Request, ServeConfig, ServeEngine, make_workload
+
+    obs.configure("null", background=False)
+    wl = make_workload("fixedpoint_solve", solver="d_iteration", n=16, slots=1)
+    eng = ServeEngine(wl, ServeConfig(termination="residual_interval"))
+    rng = np.random.default_rng(0)
+    for i in range(2):  # one slot: the second request waits for the first
+        v = rng.random(16).astype(np.float32)
+        eng.submit(Request(id=i, payload=v / v.sum(), max_new=400))
+    res = eng.run()
+    evs = [e for e in obs.telemetry().tracer.events()
+           if e[1] == "serve.admit.request"]
+    queued = {e[5]["request"]: e[5]["queued_ms"] for e in evs}
+    assert sorted(queued) == [0, 1]
+    assert 0 <= queued[0] < queued[1]
+    for rid, ms in queued.items():
+        assert res[rid].queued_s * 1e3 == pytest.approx(ms)
+    assert eng.summary()["queue_p99_ms"] == pytest.approx(
+        np.percentile([queued[0], queued[1]], 99))
+    retires = [e[5] for e in obs.telemetry().tracer.events()
+               if e[1] == "serve.retire"]
+    assert [r["requests"] for r in retires] == [[0], [1]]
+    assert all(r["n"] == 1 and r["forced"] == 0 for r in retires)
+
+
 def test_serve_engine_summary_telemetry_disabled_is_benign():
     from repro.serving import Request, ServeConfig, ServeEngine, make_workload
 
