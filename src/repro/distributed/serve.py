@@ -183,14 +183,50 @@ def make_pool_decode_step(cfg: ModelConfig, mesh: Mesh):
     return pool_step, rules
 
 
-def _prefill_scan(cfg, rules, params, prompt, plen, cslot, max_prompt_len: int):
-    """Scan the decode step over a padded prompt into a one-slot cache view.
+def prefill_path(cfg: ModelConfig, cache: Any, max_prompt_len: int) -> str:
+    """How a prompt is admitted into a one-slot cache view: ``"one_pass"``
+    or ``"scan"`` (``cache`` may hold arrays or ``ShapeDtypeStruct``s).
+
+    One causal pass (:func:`transformer.forward_prefill`) yields exactly the
+    rows the decode step would write when the view is plain ``k``/``v`` of a
+    dense stack that holds the whole padded prompt.  Every other cache keeps
+    the per-position decode scan: moe routing drops tokens over capacity per
+    group, so a prompt-wide pass routes differently; ssm and hybrid state is
+    recurrent; gemma3's local layers and windows shorter than the prompt
+    write modulo the window; int8 K/V is attended already quantized.
+    """
+    one = (
+        cfg.family == "dense" and not cfg.pattern_local
+        and set(cache) == {"k", "v"} and cache["k"].shape[2] >= max_prompt_len
+    )
+    return "one_pass" if one else "scan"
+
+
+def _prefill_slot(cfg, rules, params, prompt, plen, cslot, max_prompt_len: int):
+    """Prefill a padded prompt into a zeroed one-slot cache view.
 
     Shared by the contiguous and paged offset-prefill builders so both
     admission paths trace the exact same jaxpr (bit-exactness discipline).
-    Positions ``>= plen`` run but are masked out of the carried cache; the
-    last live position's logits are latched.
+    Returns (cslot, last-live-position logits [V]).  The path is
+    :func:`prefill_path`'s: dense stacks take one causal forward pass, whose
+    K/V rows land at positions ``[0, max_prompt_len)`` (zeros from ``plen``
+    on); every other family scans the decode step over the padded prompt,
+    positions ``>= plen`` run but masked out of the carried cache, and
+    latches the last live position's logits.
     """
+    prompt = prompt[:max_prompt_len]
+    if prefill_path(cfg, cslot, max_prompt_len) == "one_pass":
+        with shd.sharding_ctx(cfg, rules):
+            last_logits, kv = transformer.forward_prefill(
+                params, prompt[None], plen, cfg
+            )
+        cslot = {
+            n: jax.lax.dynamic_update_slice_in_dim(
+                cslot[n], kv[n].astype(cslot[n].dtype), 0, axis=2
+            )
+            for n in cslot
+        }
+        return cslot, last_logits[0]
 
     def body(carry, xs):
         c, last = carry
@@ -207,7 +243,7 @@ def _prefill_scan(cfg, rules, params, prompt, plen, cslot, max_prompt_len: int):
     (cslot, last_logits), _ = jax.lax.scan(
         body,
         (cslot, jnp.zeros((cfg.vocab,), jnp.float32)),
-        (prompt[:max_prompt_len], jnp.arange(max_prompt_len, dtype=jnp.int32)),
+        (prompt, jnp.arange(max_prompt_len, dtype=jnp.int32)),
         unroll=1,
     )
     return cslot, last_logits
@@ -219,9 +255,11 @@ def make_slot_prefill_step(cfg: ModelConfig, mesh: Mesh, max_prompt_len: int):
     ``slot_prefill(params, prompt [Lmax], plen, cache, slot) ->
     (last_logits [V], cache)``: the retired slot's cache slice is zeroed
     (recurrent SSM/conv state must not leak between requests; attention
-    positions beyond the new length are masked anyway) and the decode step
-    is scanned over the padded prompt, masking positions ``>= plen`` — one
-    jitted dispatch per admission, shapes fixed by ``max_prompt_len``, so
+    positions beyond the new length are masked anyway) and
+    :func:`_prefill_slot` fills it: one causal forward pass for dense
+    stacks, the decode step scanned over the padded prompt for moe, ssm,
+    hybrid, gemma3 and int8 K/V (:func:`prefill_path`).  One jitted
+    dispatch per admission, shapes fixed by ``max_prompt_len``, so
     admission never recompiles.  The rest of the pool is untouched, so live
     slots keep decoding across admissions.
     """
@@ -236,7 +274,7 @@ def make_slot_prefill_step(cfg: ModelConfig, mesh: Mesh, max_prompt_len: int):
             ),
             cache,
         )
-        cslot, last_logits = _prefill_scan(
+        cslot, last_logits = _prefill_slot(
             cfg, rules, params, prompt, plen, cslot, max_prompt_len
         )
         cache = jax.tree_util.tree_map_with_path(
@@ -434,8 +472,10 @@ def make_paged_slot_prefill_step(cfg: ModelConfig, mesh: Mesh,
     slot, table_row [nb], write_mask [nb]) -> (last_logits [V], pages,
     tables, slot_state)``.
 
-    Runs the shared :func:`_prefill_scan` over a zeroed full-length view
-    (same jaxpr as the contiguous admission — bit-exactness), then scatters
+    Runs the shared :func:`_prefill_slot` over a zeroed full-length view
+    (same jaxpr as the contiguous admission — bit-exactness): one causal
+    forward pass for dense stacks, the per-position decode scan for moe,
+    hybrid and int8 K/V (:func:`prefill_path`).  It then scatters
     whole blocks into the physical pool: logical block ``j`` goes to
     ``table_row[j]`` where ``write_mask[j]``, else to the trash block —
     shared prefix blocks are *skip-written* (their recomputed content is
@@ -458,7 +498,7 @@ def make_paged_slot_prefill_step(cfg: ModelConfig, mesh: Mesh,
             ),
             slot_state,
         )
-        cslot, last_logits = _prefill_scan(
+        cslot, last_logits = _prefill_slot(
             cfg, rules, params, prompt, plen, {**view0, **slot0},
             max_prompt_len,
         )

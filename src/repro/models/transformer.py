@@ -15,7 +15,7 @@ Interfaces:
   init_params(cfg, key)                              -> params
   forward_train(params, batch, cfg, remat_policy)    -> (loss, metrics)
   init_cache(cfg, batch, max_len)                    -> cache
-  forward_prefill(params, batch, cfg)                -> (last_logits, cache)
+  forward_prefill(params, tokens, plen, cfg)         -> (last_logits, {k, v})
   forward_decode(params, tok, cache, cache_len, cfg) -> (logits, cache)
 """
 
@@ -130,19 +130,27 @@ def init_params(cfg: ModelConfig, key) -> dict[str, Any]:
 # ---------------------------------------------------------------------------
 
 
-def _attn_block(lp, x, cfg, positions, *, theta, window, impl):
+def _attn_block_kv(lp, x, cfg, positions, *, theta, window, impl):
+    """Attention sub-block.  Returns (x, k, v): ``k``/``v`` are the layer's
+    post-RoPE rows at ``n_kv_heads`` — what a decode cache stores."""
     from repro.distributed.sharding import kv_repeat_factor
 
     h = rmsnorm(x, lp["ln1"], cfg.norm_eps)
     q, k, v = qkv_project(lp["attn"], h, cfg, positions, theta)
+    k_att, v_att = k, v
     rep = kv_repeat_factor(cfg.n_heads, cfg.n_kv_heads)
     if rep > 1:  # make the kv head count TP-divisible (see sharding.py)
-        k = constrain(jnp.repeat(k, rep, axis=2), "k")
-        v = constrain(jnp.repeat(v, rep, axis=2), "v")
-    o = attention(q, k, v, causal=cfg.causal, window=window, impl=impl,
+        k_att = constrain(jnp.repeat(k, rep, axis=2), "k")
+        v_att = constrain(jnp.repeat(v, rep, axis=2), "v")
+    o = attention(q, k_att, v_att, causal=cfg.causal, window=window, impl=impl,
                   chunk=min(cfg.attn_chunk, x.shape[1]))
     B, S = x.shape[:2]
-    return constrain(x + o.reshape(B, S, -1) @ lp["attn"]["wo"], "tokens")
+    return constrain(x + o.reshape(B, S, -1) @ lp["attn"]["wo"], "tokens"), k, v
+
+
+def _attn_block(lp, x, cfg, positions, *, theta, window, impl):
+    return _attn_block_kv(lp, x, cfg, positions, theta=theta, window=window,
+                          impl=impl)[0]
 
 
 def _mlp_block(lp, x, cfg):
@@ -433,6 +441,44 @@ def _decode_attn_layer(lp, x_tok, kc, vc, cache_len, cfg, *, theta, window,
     if ks is not None:
         return x, kc, vc, ks, vs
     return x, kc, vc
+
+
+def forward_prefill(params, tokens, plen, cfg: ModelConfig):
+    """One causal pass over padded prompts: the decode cache's rows at once.
+
+    tokens: [B, S] int32, each row a prompt of ``plen`` tokens then padding;
+    plen: [B] or a scalar.  Returns (last_logits [B, V] at position
+    ``plen - 1``, {"k", "v"} [L, B, S, KV, hd]): each layer's post-RoPE K/V
+    rows, which the per-token :func:`forward_decode` loop would have written
+    at positions ``< plen``, and zeros from ``plen`` on.  Padding follows
+    the prompt, so the causal mask keeps it out of every live row, and only
+    row ``plen - 1`` goes through the final norm and the head.
+
+    Dense stacks only: every other family's decode cache holds what one
+    pass does not give (``distributed/serve.py:prefill_path``).
+    """
+    if cfg.family != "dense" or cfg.pattern_local:
+        raise ValueError(f"{cfg.name}: one-pass prefill serves dense stacks only")
+    B, S = tokens.shape
+    plen = jnp.broadcast_to(jnp.asarray(plen, jnp.int32), (B,))
+    x = jnp.take(params["embed"], tokens, axis=0).astype(dtype_of(cfg.compute_dtype))
+    x = constrain(x, "tokens")
+    positions = jnp.arange(S)[None, :]
+
+    def body(h, lp):
+        h, k, v = _attn_block_kv(
+            lp, h, cfg, positions,
+            theta=cfg.rope_theta, window=cfg.sliding_window, impl=cfg.attn_impl,
+        )
+        h, _ = _mlp_block(lp, h, cfg)
+        return h, (k, v)
+
+    x, (k, v) = jax.lax.scan(body, x, params["layers"], unroll=cfg.scan_unroll)
+    live = (jnp.arange(S)[None, :] < plen[:, None])[None, :, :, None, None]
+    kv = {"k": jnp.where(live, k, 0), "v": jnp.where(live, v, 0)}
+    x = jnp.take_along_axis(x, (plen - 1)[:, None, None], axis=1)  # [B, 1, d]
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    return _logits(params, x, cfg)[:, 0], kv
 
 
 def forward_decode(params, tokens, cache, cache_len, cfg: ModelConfig):

@@ -234,6 +234,10 @@ class PagedDecodePool:
         slot_prefill, _ = dserve.make_paged_slot_prefill_step(
             cfg, mesh, max_prompt_len, max_len, block_size
         )
+        self.prefill_path = dserve.prefill_path(
+            cfg, jax.eval_shape(lambda: transformer.init_cache(cfg, 1, max_len)),
+            max_prompt_len,
+        )
 
         def _step(params, state, active):
             logits, pages2, slot2 = pool_step(
@@ -402,7 +406,8 @@ class PagedDecodePool:
             mask[: len(blocks)] = write_mask
             padded = np.zeros((self.max_prompt_len,), np.int32)
             padded[:plen] = prompt
-        with obs.span("paged.prefill"):  # the dispatch and its first token
+        # the dispatch and its first token
+        with obs.span("paged.prefill", path=self.prefill_path):
             with self.mesh:
                 self.state = self._jadmit(
                     params, self.state, jnp.asarray(padded), jnp.int32(plen),

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.asynchrony.protocols import RES_INIT
 from repro.distributed import serve as dserve
 from repro.models import transformer
@@ -57,6 +58,10 @@ class DecodePool:
         self.slots, self.max_len, self.max_prompt_len = slots, max_len, max_prompt_len
         pool_step, self.rules = dserve.make_pool_decode_step(cfg, mesh)
         slot_prefill, _ = dserve.make_slot_prefill_step(cfg, mesh, max_prompt_len)
+        self.prefill_path = dserve.prefill_path(
+            cfg, jax.eval_shape(lambda: transformer.init_cache(cfg, 1, max_len)),
+            max_prompt_len,
+        )
 
         def _step(params, state, active):
             logits, cache2 = pool_step(
@@ -134,12 +139,14 @@ class DecodePool:
             )
         padded = np.zeros((self.max_prompt_len,), np.int32)
         padded[:plen] = prompt
-        with self.mesh:
-            self.state = self._jadmit(
-                params, self.state, jnp.asarray(padded), jnp.int32(plen),
-                jnp.int32(slot),
-            )
-        return int(self.state["tokens"][slot])
+        # the dispatch and its first token
+        with obs.span("pool.prefill", path=self.prefill_path):
+            with self.mesh:
+                self.state = self._jadmit(
+                    params, self.state, jnp.asarray(padded), jnp.int32(plen),
+                    jnp.int32(slot),
+                )
+            return int(self.state["tokens"][slot])
 
 
 class FixedPointPool:

@@ -454,6 +454,47 @@ def test_admit_request_span_carries_queue_wait():
     assert all(r["n"] == 1 and r["forced"] == 0 for r in retires)
 
 
+@pytest.mark.parametrize("arch,kv_dtype,paged,want", [
+    ("llama3.2-1b", "bf16", True, "one_pass"),
+    ("minicpm-2b", "bf16", False, "one_pass"),
+    ("zamba2-2.7b", "bf16", True, "scan"),
+    ("llama4-scout-17b-a16e", "bf16", True, "scan"),
+    ("gemma3-12b", "bf16", False, "scan"),
+    ("llama3.2-1b", "int8", True, "scan"),
+])
+def test_prefill_span_names_the_admission_path(arch, kv_dtype, paged, want):
+    """``paged.prefill`` (block-paged pool) and ``pool.prefill`` (contiguous
+    pool) say which admission ran: one causal pass for dense K/V caches,
+    the decode scan for hybrid, moe, gemma3 and int8 K/V."""
+    import dataclasses
+
+    import jax
+    from jax.sharding import AxisType
+
+    from repro.configs import registry
+    from repro.models import transformer
+    from repro.serving import DecodePool, PagedDecodePool
+
+    cfg = dataclasses.replace(registry.get_smoke_config(arch),
+                              kv_cache_dtype=kv_dtype)
+    mesh = jax.make_mesh((1,), ("data",), devices=jax.devices()[:1],
+                         axis_types=(AxisType.Auto,))
+    obs.configure("null", background=False)
+    if paged:
+        pool = PagedDecodePool(cfg, mesh, slots=1, max_len=16, max_prompt_len=8,
+                               block_size=8)
+        admit = {"max_new": 4}
+    else:
+        pool = DecodePool(cfg, mesh, slots=1, max_len=16, max_prompt_len=8)
+        admit = {}
+    params = transformer.init_params(cfg, jax.random.PRNGKey(0))
+    pool.admit(params, np.arange(1, 6), 0, **admit)
+    name = "paged.prefill" if paged else "pool.prefill"
+    spans = [e[5] for e in obs.telemetry().tracer.events() if e[1] == name]
+    assert spans == [{"path": want}]
+    assert pool.prefill_path == want
+
+
 def test_serve_engine_summary_telemetry_disabled_is_benign():
     from repro.serving import Request, ServeConfig, ServeEngine, make_workload
 
