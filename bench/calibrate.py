@@ -7,9 +7,10 @@ For each seed, in this one process: the cell's run (a window of
 ``--seconds``), its compared numbers, and the same numbers for the
 lower-precision control put in the program's place on the same inputs:
 
-- LLM cells: the control is the reference computed with float8 (e4m3)
-  matmul operands; its reading is the widest gap of the token it puts first
-  at each position of the served requests.
+- LLM cells: the control is the reference computed with its module's
+  ``control`` rounding of the matmul operands (float8 e4m3 for ``dense``);
+  its reading is the widest gap of the token it puts first at each
+  position of the served requests.
 - Fixed-point cells: the control is the reference iteration run in
   bfloat16; its reading is the worst float64 true residual of its answers
   to the first requests of the run.  With ``--default-precision 1`` the
@@ -43,11 +44,11 @@ def control_reading(cell, run, seed: int, *, requests: int = 16):
     kind = cell.config["kind"]
     if kind == "llm_paged":
         from bench.kinds import llm_paged
-        from bench.reference import dense
 
         shape = llm_paged.pool_shape(cell.config, cell.traffic)
         gap, n, _ = llm_paged._widest_gap(
-            cell.config, cell.traffic, seed, run.recs, shape, rnd=dense.fp8
+            cell.config, cell.traffic, seed, run.recs, shape, run.reference,
+            rnd=run.reference.control,
         )
         return {"control_logit_gap_max": gap, "control_tokens": n}
     if kind == "fixedpoint":

@@ -5,6 +5,7 @@ through the measured window and checks its outputs, and returns a
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import gc
 from typing import Any, Dict, List, Optional
@@ -29,6 +30,7 @@ class RunData:
     trace_summary: Optional[dict] = None  # trace.reduce() of it
     lag_s: List[float] = dataclasses.field(default_factory=list)
     peaks: Optional[dict] = None
+    reference: Any = None  # the configuration's reference module (LLM kinds)
 
     def in_window(self):
         """Records of the requests due in the window (all were sent)."""
@@ -56,6 +58,20 @@ def free_device_memory() -> None:
     gc.collect()
     jax.clear_caches()
     gc.collect()
+
+
+@contextlib.contextmanager
+def frozen_heap():
+    """Collect once, then keep what set-up left out of the collector until
+    the window and its drain are over, as servers do after start-up: a
+    full collection of set-up's objects inside the window took 145 ms on a
+    TPU v5e host, against ~9 ms for all the window's other collections."""
+    gc.collect()
+    gc.freeze()
+    try:
+        yield
+    finally:
+        gc.unfreeze()
 
 
 def checks_entry(value, limit) -> dict:

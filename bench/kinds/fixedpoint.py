@@ -28,6 +28,7 @@ from bench.kinds import (
     checks_entry,
     chip_peaks,
     free_device_memory,
+    frozen_heap,
     memory_peak_bytes,
 )
 from bench.reference.pagerank import PageRank
@@ -86,11 +87,12 @@ def run(cell, seed: int, seconds: float, trace: bool, *, devices, since_start):
             return Request(id=i, payload=bank[i % len(bank)], max_new=t["max_iters"])
 
         tracing = serve_loop.Tracing(*[f * seconds for f in t["trace_window"]]) if trace else None
-        setup_s = since_start()
-        recs, t0, t_end, t_drained = serve_loop.drive(
-            engine, sched, make_request, seconds, needs_done=True,
-            drain_s=t["drain_s"], tracing=tracing,
-        )
+        with frozen_heap():
+            setup_s = since_start()
+            recs, t0, t_end, t_drained = serve_loop.drive(
+                engine, sched, make_request, seconds, needs_done=True,
+                drain_s=t["drain_s"], tracing=tracing,
+            )
     peak = memory_peak_bytes(devices)
     summary = (
         trace_lib.reduce(tracing.trace)

@@ -2,45 +2,40 @@
 ``llm_decode_paged`` workload, with its default attention path and the
 default ``ServeConfig``, under a closed loop of clients.
 
-Set-up makes the weights on the device from the seed (the benchmark's own
-generator, :func:`bench.reference.dense.make_weights`), builds the pool
-from the traffic's lengths and warms up every program the window uses by
-serving one request.  After the window, a sample of the finished requests,
-drawn from the seed and holding the longest, is run through the float32
-reference: the widest gap by which a served token's reference logit lies
-below the reference's best at its position is compared with the
-configuration's limit.
+The configuration names its reference module (``"reference"``, found by
+:func:`bench.spec.reference`), which gives the program's ``ModelConfig``,
+the weights, the plain reference and the counts; this module knows no
+architecture.  Set-up makes the weights on the device from the seed,
+builds the pool from the traffic's lengths and warms up every program the
+window uses by serving one request.  After the window, a sample of the
+finished requests, drawn from the seed and holding the longest, is run
+through the float32 reference: the widest gap by which a served token's
+reference logit lies below the reference's best at its position is
+compared with the configuration's limit.
 """
 
 from __future__ import annotations
 
 import numpy as np
 
-from bench import loadgen, serve_loop
+from bench import loadgen, serve_loop, spec
 from bench import trace as trace_lib
 from bench.kinds import (
     RunData,
     checks_entry,
     chip_peaks,
     free_device_memory,
+    frozen_heap,
     memory_peak_bytes,
 )
-from bench.reference import dense
 
 
-def model_config(c: dict):
-    """The program's ``ModelConfig`` for a configuration file."""
+def model_config(c: dict, ref):
+    """The program's ``ModelConfig`` for a configuration file, as its
+    reference module ``ref`` gives it."""
     from repro.models.config import ModelConfig
 
-    m = c["model"]
-    return ModelConfig(
-        name=c["name"], family="dense", n_layers=m["n_layers"],
-        d_model=m["d_model"], n_heads=m["n_heads"], n_kv_heads=m["n_kv_heads"],
-        d_ff=m["d_ff"], vocab=m["vocab"], head_dim=m["head_dim"],
-        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"],
-        tie_embeddings=m["tie_embeddings"], param_dtype=m["dtype"],
-        compute_dtype=m["dtype"],
-    )
+    return ModelConfig(**ref.program_config(c))
 
 
 def pool_shape(c: dict, t: dict) -> dict:
@@ -97,12 +92,12 @@ def run(cell, seed: int, seconds: float, trace: bool, *, devices, since_start):
     from repro.serving.engine import Request, ServeConfig, ServeEngine
 
     c, t = cell.config, cell.traffic
-    m = c["model"]
-    cfg = model_config(c)
+    ref = spec.reference(c, cell.bench_dir)
+    cfg = model_config(c, ref)
     shape = pool_shape(c, t)
     prompts, outs = requests(c, t, seed)
 
-    params = dense.make_weights(m, seed)
+    params = ref.make_weights(c["model"], seed)
     _check_layout(cfg, params)
     mesh = build_mesh(1, 1)
     wl = make_workload(
@@ -127,11 +122,12 @@ def run(cell, seed: int, seconds: float, trace: bool, *, devices, since_start):
     tracing = (
         serve_loop.Tracing(*[f * seconds for f in t["trace_window"]]) if trace else None
     )
-    setup_s = since_start()
-    recs, t0, t_end, t_drained = serve_loop.drive(
-        engine, sched, make_request, seconds, needs_done=False,
-        drain_s=t["drain_s"], tracing=tracing,
-    )
+    with frozen_heap():
+        setup_s = since_start()
+        recs, t0, t_end, t_drained = serve_loop.drive(
+            engine, sched, make_request, seconds, needs_done=False,
+            drain_s=t["drain_s"], tracing=tracing,
+        )
     peak = memory_peak_bytes(devices)
     summary = (
         trace_lib.reduce(tracing.trace)
@@ -143,7 +139,7 @@ def run(cell, seed: int, seconds: float, trace: bool, *, devices, since_start):
     free_device_memory()
     sent = [r for r in recs.values() if r.due <= t_end]
     no_first = sum(r.first is None for r in sent)
-    gap, n_tok, n_req = _widest_gap(c, t, seed, recs, shape)
+    gap, n_tok, n_req = _widest_gap(c, t, seed, recs, shape, ref)
     limit = c["limits"]["logit_gap"]
     checks = {
         "logit_gap_max": checks_entry(gap, limit),
@@ -159,7 +155,7 @@ def run(cell, seed: int, seconds: float, trace: bool, *, devices, since_start):
         t_end=t_end, t_drained=t_drained, recs=recs, memory_peak_bytes=peak,
         correct=bool(correct), attempted=len(sent), failed=no_first,
         checks=checks, tracing=tracing, trace_summary=summary,
-        peaks=chip_peaks(devices),
+        peaks=chip_peaks(devices), reference=ref,
     )
 
 
@@ -184,15 +180,16 @@ def sample(recs, t: dict, seed: int):
     return picked
 
 
-def _widest_gap(c, t, seed, recs, shape, rnd=None):
-    """(widest gap, tokens compared, requests compared) over the sample."""
+def _widest_gap(c, t, seed, recs, shape, ref, rnd=None):
+    """(widest gap, tokens compared, requests compared) over the sample,
+    by the reference module ``ref``."""
     picked = sample(recs, t, seed)
     if not picked:
         return None, 0, 0
-    w = dense.make_weights(c["model"], seed)
+    w = ref.make_weights(c["model"], seed)
     widest, n = 0.0, 0
     for r in picked:
-        gaps = dense.served_gaps(
+        gaps = ref.served_gaps(
             c["model"], w, r.req.prompt, r.result.output,
             seq_len=shape["max_len"], rows_len=t["output_tokens"]["max"],
             rnd=rnd,

@@ -9,7 +9,6 @@ from __future__ import annotations
 
 from typing import List, Optional
 
-from bench import flops
 from bench.stats import percentile
 
 
@@ -22,6 +21,12 @@ def waits_ms(run, until) -> List[float]:
         t = until(r)
         out.append(((t if t is not None else run.t_drained) - r.due) * 1e3)
     return out
+
+
+def solve_waits_ms(run) -> List[float]:
+    """Due time to certified solution, per request due in the window."""
+    return waits_ms(run, lambda r: r.done if r.result is not None
+                    and r.result.converged else None)
 
 
 def p95(values) -> Optional[float]:
@@ -94,7 +99,9 @@ def decode_positions(run, lo: float, hi: float) -> int:
 def model_flops_in_window(run) -> float:
     """Operations the model needs for the real tokens of the window:
     prompts admitted (first delivery) in it and output tokens delivered in
-    it, padding excluded."""
+    it, padding excluded, as the configuration's reference module counts
+    them."""
+    span_flops = run.reference.span_flops
     m = run.config["model"]
     tot = 0.0
     for r in run.recs.values():
@@ -102,9 +109,9 @@ def model_flops_in_window(run) -> float:
         d = [(t, k) for t, k in r.deliveries if t <= run.t_end]
         if not d:
             continue
-        tot += flops.span_flops(m, 0, p, head_from=p - 1)
+        tot += span_flops(m, 0, p, head_from=p - 1, run=run)
         k = d[-1][1]
-        tot += flops.span_flops(m, p, p + k - 1, head_from=0)
+        tot += span_flops(m, p, p + k - 1, head_from=0, run=run)
     return tot
 
 

@@ -1,5 +1,15 @@
-"""Plain float32 reference of a dense decoder, and the weights it shares
-with the program under test.
+"""Plain float32 reference of a dense decoder, the weights it shares with
+the program under test, and the counts of operations and bytes that the
+readers divide by: what an LLM configuration with ``"reference": "dense"``
+gives the harness (``bench.spec.reference``).
+
+- :func:`program_config`: the program's ``ModelConfig`` fields for the
+  configuration file (the caller builds it, so this imports nothing of
+  the program);
+- :func:`make_weights`, :func:`served_gaps` and :data:`control`, the
+  rounding of the lower-precision control;
+- :func:`span_flops` and :func:`decode_bytes`, from ``bench/flops.py``'s
+  dense formulas.
 
 Equations (those of the program's dense path; the departures from the
 published MiniCPM are listed in its configuration file under
@@ -23,6 +33,37 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from bench import flops
+
+
+def program_config(c: dict) -> dict:
+    """Keyword arguments of the program's ``ModelConfig`` for a
+    configuration file."""
+    m = c["model"]
+    return dict(
+        name=c["name"], family="dense", n_layers=m["n_layers"],
+        d_model=m["d_model"], n_heads=m["n_heads"], n_kv_heads=m["n_kv_heads"],
+        d_ff=m["d_ff"], vocab=m["vocab"], head_dim=m["head_dim"],
+        rope_theta=m["rope_theta"], norm_eps=m["norm_eps"],
+        tie_embeddings=m["tie_embeddings"], param_dtype=m["dtype"],
+        compute_dtype=m["dtype"],
+    )
+
+
+def span_flops(m: dict, start: int, stop: int, *, head_from: int, run=None) -> float:
+    """Operations for the tokens at positions ``start .. stop - 1``, the
+    output head counted from ``head_from`` on.  ``run`` (a ``RunData``) is
+    there for counts that depend on what the program did, such as routing;
+    a dense count needs only the shapes."""
+    return flops.span_flops(m, start, stop, head_from=head_from)
+
+
+def decode_bytes(m: dict, ticks: int, positions: int, run=None) -> int:
+    """Least bytes that ``ticks`` decode ticks read, whose active slots
+    attend ``positions`` positions in all: every weight once a tick, and
+    the K/V rows of each position attended."""
+    return flops.weight_bytes(m) * ticks + flops.kv_bytes_per_token(m) * positions
 
 
 def _dtype(m: dict):
@@ -106,6 +147,11 @@ def fp8(x, axis=-1):
     amax = jnp.max(jnp.abs(x), axis=axis, keepdims=True)
     s = jnp.where(amax > 0, amax / 448.0, 1.0)
     return (x / s).astype(jnp.float8_e4m3fn).astype(jnp.float32) * s
+
+
+# the control: the reference with float8 matmul operands, the precision
+# below the bfloat16 that the configuration serves in
+control = fp8
 
 
 def hidden_states(m: dict, w, tokens, rnd=identity):

@@ -123,7 +123,9 @@ def drive(
 ):
     """Offer ``schedule`` for ``seconds``, then keep stepping (sending
     nothing new) until every request sent has its first token, or, with
-    ``needs_done``, has completed; at most ``drain_s`` more.
+    ``needs_done``, has completed; at most ``drain_s`` more, not counting
+    the time that starting and stopping the profiler held the loop (its
+    stop writes the trace, over a second on a loaded host).
 
     Returns ``(records by index, t0, t_end, t_drained)``.
     """
@@ -133,6 +135,7 @@ def drive(
     t_end = t0 + seconds
     seen = set(engine.results)
     closed = False  # no more requests are sent
+    profiler_s = 0.0  # the loop's time inside tracing.poll
 
     def satisfied(r: Rec) -> bool:
         return r.done is not None if needs_done else r.first is not None
@@ -151,12 +154,15 @@ def drive(
                     recs[i] = by_id[req.id] = r
                     if hasattr(schedule, "sent"):
                         schedule.sent(i, r.sent - t0)
-        elif all(satisfied(r) for r in recs.values()) or now > t_end + drain_s:
+        elif (all(satisfied(r) for r in recs.values())
+              or now > t_end + drain_s + profiler_s):
             break
         if tracing is not None:
             # after the submissions: closing the trace stalls the loop
             # while the profiler writes and the trace is read
+            before = clock()
             tracing.poll(el)
+            profiler_s += clock() - before
         if engine.queue or engine.active.any():
             tick0, ts = engine.tick, clock()
             with jax.profiler.TraceAnnotation("bench.step"):

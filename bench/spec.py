@@ -2,9 +2,11 @@
 
 A cell names a configuration and a traffic mix; the configuration's file
 is the one ``BENCHMARK.json`` gives, the traffic mix is
-``bench/traffic/<traffic>.json`` and every metric is read by
-``bench/metrics/<metric name>.py``.  A later cell, mix or metric is a new
-entry and new files: nothing here changes.
+``bench/traffic/<traffic>.json``, every metric is read by
+``bench/metrics/<metric name>.py`` and an LLM configuration's plain
+reference and counts are ``bench/reference/<its "reference">.py``.  A later
+cell, mix, metric or architecture is a new entry and new files: nothing
+here changes.
 """
 
 from __future__ import annotations
@@ -27,6 +29,7 @@ class Cell:
     traffic: dict  # the traffic file's contents
     end_to_end: List[dict]  # this cell's end-to-end metric entries
     per_layer: List[dict]  # this cell's per-layer metric entries
+    bench_dir: Path  # where its files were found
 
 
 def load_json(path: Path) -> dict:
@@ -55,16 +58,29 @@ def load_cell(name: str, root: Path = ROOT, bench_dir: Path = BENCH) -> Cell:
             "workloads" not in m and m["moves"] in reported
         )
     ]
-    return Cell(name, int(w["chips"]), config, traffic, e2e, per_layer)
+    return Cell(name, int(w["chips"]), config, traffic, e2e, per_layer, bench_dir)
+
+
+def _load(path: Path, name: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
 
 
 def reader(metric: str, bench_dir: Path = BENCH) -> Callable:
     """The ``read(run) -> float | None`` function of ``metrics/<metric>.py``."""
-    path = bench_dir / "metrics" / f"{metric}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_metric_{metric}", path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod.read
+    return _load(bench_dir / "metrics" / f"{metric}.py", f"bench_metric_{metric}").read
+
+
+def reference(config: dict, bench_dir: Path = BENCH):
+    """The module ``reference/<name>.py`` that an LLM configuration names by
+    its ``"reference"`` key, which has no default.  It gives the program's
+    ``ModelConfig`` fields, the seeded weights, the plain reference and its
+    lower-precision control, and the counts of operations and bytes that
+    the readers divide by."""
+    name = config["reference"]
+    return _load(bench_dir / "reference" / f"{name}.py", f"bench_reference_{name}")
 
 
 def read_metrics(entries: List[dict], run, bench_dir: Path = BENCH) -> Dict[str, dict]:

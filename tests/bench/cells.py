@@ -1,6 +1,7 @@
 """A throwaway benchmark at smoke size in a temporary directory: its own
-BENCHMARK.json, configuration, traffic and metric files, found by name
-exactly as the real ones are."""
+BENCHMARK.json, configuration, traffic, metric and reference files, found
+by name exactly as the real ones are.  Each real cell stands as the tiny
+cell of its configuration's ``kind``."""
 
 import json
 import shutil
@@ -14,6 +15,8 @@ TINY_LLM = {
     "n_layers": 2, "d_model": 64, "n_heads": 4, "n_kv_heads": 4,
     "head_dim": 16, "d_ff": 128, "vocab": 512, "token_ids_below": 500,
 }
+# the tiny cell that stands for every real cell of a configuration kind
+TINY_CELL = {"llm_paged": "tiny.chat", "fixedpoint": "tiny.ppr"}
 # a metric that no real cell has: the harness must find it by its name
 THROWAWAY_METRIC = '''
 def read(run):
@@ -21,11 +24,24 @@ def read(run):
 '''
 
 
-def make_bench(tmp: Path, *, seconds_hint: float = 1.5) -> Path:
-    spec = json.loads((ROOT / "BENCHMARK.json").read_text())
+def tiny_cells(spec: dict, real: Path) -> dict:
+    """Each cell of ``spec`` -> the tiny cell of its configuration's kind,
+    read from the configuration files under ``real``."""
+    files = {c["name"]: c["file"] for c in spec["configs"]}
+    kind = {n: json.loads((real / f).read_text())["kind"] for n, f in files.items()}
+    return {w["name"]: TINY_CELL[kind[w["config"]]] for w in spec["workloads"]}
+
+
+def make_bench(tmp: Path, *, real: Path = ROOT) -> Path:
+    """The tiny benchmark in ``tmp``, with the metric lists of the benchmark
+    in ``real`` (its BENCHMARK.json and the configuration files it names)."""
+    spec = json.loads((real / "BENCHMARK.json").read_text())
+    tiny = tiny_cells(spec, real)
     (tmp / "bench/configs").mkdir(parents=True)
     (tmp / "bench/traffic").mkdir(parents=True)
     shutil.copytree(ROOT / "bench/metrics", tmp / "bench/metrics")
+    shutil.copytree(ROOT / "bench/reference", tmp / "bench/reference",
+                    ignore=shutil.ignore_patterns("__pycache__"))
     (tmp / "bench/metrics/deliveries_seen.py").write_text(THROWAWAY_METRIC)
 
     c = json.loads((ROOT / "bench/configs/minicpm-2b.json").read_text())
@@ -63,13 +79,9 @@ def make_bench(tmp: Path, *, seconds_hint: float = 1.5) -> Path:
         {"name": "tiny.ppr", "config": "tiny-pagerank", "traffic": "tiny_ppr",
          "chips": 1, "why": "smoke"},
     ]
-    for m in spec["end_to_end"]:
+    for m in spec["end_to_end"] + spec["per_layer"]:
         if "workloads" in m:
-            m["workloads"] = ["tiny.chat" if "minicpm" in w else "tiny.ppr"
-                              for w in m["workloads"]]
-    for m in spec["per_layer"]:
-        m["workloads"] = ["tiny.chat" if "minicpm" in w else "tiny.ppr"
-                          for w in m["workloads"]]
+            m["workloads"] = list(dict.fromkeys(tiny[w] for w in m["workloads"]))
     spec["per_layer"].append(
         {"name": "deliveries_seen", "unit": "count", "better": "higher",
          "source": "host_clock", "layer": "load generator", "moves": "llm_tok_s",

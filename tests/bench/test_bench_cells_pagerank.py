@@ -25,7 +25,7 @@ def test_result_line(bench_dir):
     out = cells.run(bench_dir, "tiny.ppr", SEED)
     assert out["correct"] is True and out["failed"] == 0
     assert out["attempted"] >= 25  # 20/s over 1.5 s, every one due is sent
-    assert set(out["metrics"]) == {"solve_p95_ms", "setup_s"}
+    assert set(out["metrics"]) == {"solve_p50_ms", "setup_s"}
     res = out["checks"]["true_residual_max"]
     assert 0 < res["value"] < res["limit"] == 1e-6
 
@@ -37,6 +37,7 @@ def test_traced_run(bench_dir):
     assert got["ticks_per_solve"]["value"] > 10
     assert 0 < got["host_share.ppr"]["value"] < 100
     assert "gen_lag_p99_ms" in got
+    assert got["solve_p95_ms.ppr"]["value"] > 0
 
 
 def _step_unchanged(self, params, wstate, active, tick):

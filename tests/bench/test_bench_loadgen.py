@@ -131,3 +131,50 @@ def test_a_stall_is_charged_to_the_requests_behind_it():
     # requests due during a 50 ms step wait for it: lag grows past 10 ms
     assert max(sched.lag_s) > 0.01
     assert len(sched.lag_s) == len(recs)
+
+
+class TwoStepEngine(SlowEngine):
+    """Completes a request at the second step after its submission, as a
+    solve that takes more than one dispatch does."""
+
+    def __init__(self, dt):
+        super().__init__(dt)
+        self.held = []
+
+    def step(self):
+        time.sleep(self.dt)
+        for r in self.held:
+            self.results[r.id] = types.SimpleNamespace(n_tokens=1, converged=True)
+        self.held, self.queue, self.tick = self.queue, [], self.tick + 1
+        self.active[:] = bool(self.held)
+
+
+class StallingProfiler:
+    """A stand-in for ``serve_loop.Tracing`` whose stop, at ``at`` seconds
+    into the window, holds the loop for ``dt`` seconds (as writing a trace
+    does on a loaded host)."""
+
+    on, steps = False, []
+
+    def __init__(self, at, dt):
+        self.at, self.dt, self.stopped = at, dt, False
+
+    def poll(self, elapsed):
+        if not self.stopped and elapsed >= self.at:
+            self.stopped = True
+            time.sleep(self.dt)
+
+    def close(self):
+        pass
+
+
+def test_the_profilers_stall_is_not_charged_to_the_drain():
+    sched = loadgen.OpenLoop(200.0, 0.3, seed=4)
+    recs, t0, t_end, t_drained = serve_loop.drive(
+        TwoStepEngine(0.005), sched, lambda i: types.SimpleNamespace(id=i), 0.3,
+        needs_done=True, drain_s=0.1, tracing=StallingProfiler(0.25, 0.5))
+    # requests due during the stall are sent after it and still complete
+    assert len(recs) == len(sched)
+    assert all(r.done is not None for r in recs.values())
+    assert max(r.sent for r in recs.values()) > t_end + 0.3
+    assert t_drained < t_end + 0.1 + 0.5 + 0.3

@@ -84,7 +84,8 @@ def llm_run():
     tr.obs_spans = []
     summary = {"window_s": 4.0, "busy_s": 3.0,
                "program_s": {"_fused_loop": 2.0, "_admit": 0.5}}
-    return _run(recs, tracing=tr, trace_summary=summary)
+    return _run(recs, tracing=tr, trace_summary=summary,
+                reference=spec.reference({"reference": "dense"}))
 
 
 def test_llm_end_to_end_readers(llm_run):
@@ -144,7 +145,8 @@ def test_fixed_point_readers():
                trace_summary={"window_s": 4.0, "busy_s": 1.0,
                               "program_s": {"_fused_loop": 0.5}})
     # 200, 100 and 3000 ms (drain end 112 - due 109)
-    assert read("solve_p95_ms", run) == pytest.approx(3000.0)
+    assert read("solve_p95_ms.ppr", run) == pytest.approx(3000.0)
+    assert read("solve_p50_ms", run) == pytest.approx(200.0)
     assert read("ticks_per_solve", run) == pytest.approx(40.0)
     assert read("gen_lag_p99_ms", run) == pytest.approx(3.0)
     assert read("idle_share.ppr", run) == pytest.approx(75.0)

@@ -43,7 +43,7 @@ def test_paged_prefill_then_decode_matches_the_reference():
 
     c = tiny_config()
     m = c["model"]
-    cfg = llm_paged.model_config(c)
+    cfg = llm_paged.model_config(c, dense)
     w = dense.make_weights(m, seed=2**35 + 1)
     llm_paged._check_layout(cfg, w)
     mesh = build_mesh(1, 1)
